@@ -37,6 +37,7 @@ from repro.core import (  # noqa: E402
     factor,
     plan_banded,
 )
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core.banded import band_matvec, random_banded  # noqa: E402
 from repro.obs.cost import solver_stage_costs  # noqa: E402
 from repro.serve import SolverEngine  # noqa: E402
@@ -266,6 +267,7 @@ def main(argv=None) -> None:
                     help="directory for BENCH_batched.json "
                          "(default: the repo root)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     report = TracedReport("batched")
     print("name,us_per_call,derived", flush=True)
     run(report, smoke=args.smoke)

@@ -34,6 +34,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
 import numpy as np  # noqa: E402
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core import SaPOptions  # noqa: E402
 from repro.core.banded import random_banded  # noqa: E402
 from repro.serve import AsyncSolverService, SolverEngine  # noqa: E402
@@ -165,6 +166,7 @@ def main(argv=None) -> None:
                     help="directory for BENCH_serve.json "
                          "(default: the repo root)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     report = Report("serve")
     print("name,us_per_call,derived", flush=True)
     meta = run(report, smoke=args.smoke)

@@ -19,6 +19,9 @@ from benchmarks.trajectory import append_history  # noqa: E402
 def main() -> None:
     import jax
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     report = Report()
     out = repo_root_default()  # committed trajectory files live at the root
     history = out / "BENCH_history.jsonl"  # append-only perf trajectory

@@ -65,20 +65,46 @@ def band_to_dense(band: jax.Array) -> jax.Array:
     return out
 
 
+def _block_windows(band, k: int, xp=jnp):
+    """(n, 2K+1) band rows, n a multiple of K -> (n/K, K, 3K) windows.
+
+    Row r's window spans the three block columns br-1 .. br+1 of its block
+    row br = r // K: band column j lands at window index o + j, o = r % K.
+    Every row is padded to 3K+1 entries and read back with row stride 3K,
+    so row o of a block row starts o entries later, which is that shift --
+    pure pad/reshape/slice, no scatter.
+    """
+    n, w = band.shape
+    skew = xp.pad(
+        band.reshape(n // k, k, w), ((0, 0), (0, 0), (0, 3 * k + 1 - w))
+    )
+    win = skew.reshape(n // k, k * (3 * k + 1))[:, : 3 * k * k]
+    return win.reshape(n // k, k, 3 * k)
+
+
 def band_matvec(band: jax.Array, x: jax.Array) -> jax.Array:
-    """y = A @ x with A in band storage.  x: (N,) or (N, R)."""
+    """y = A @ x with A in band storage.  x: (N,) or (N, R).
+
+    Each block row of K rows multiplies its (K, 3K) window by the 3K
+    entries of x under it (:func:`_block_windows`): one elementwise product
+    and one reduction, where a shifted sum over the 2K+1 diagonals lowers
+    to 2K+1 ops (a TPU compile of minutes at K = 200 inside a solve loop).
+    Entries of the band that fall outside the matrix meet zero padding of x.
+    """
     n, w = band.shape
     k = (w - 1) // 2
     squeeze = x.ndim == 1
     if squeeze:
         x = x[:, None]
-    y = jnp.zeros((n, x.shape[1]), jnp.promote_types(band.dtype, x.dtype))
-    for j in range(w):
-        shift = j - k  # y[r] += band[r, j] * x[r + shift]
-        xs = jnp.roll(x, -shift, axis=0)
-        rows = jnp.arange(n) + shift
-        valid = ((rows >= 0) & (rows < n))[:, None]
-        y = y + jnp.where(valid, band[:, j : j + 1] * xs, 0.0)
+    if k == 0:
+        y = band * x
+    else:
+        nb = -(-n // k)
+        pad = nb * k - n
+        win = _block_windows(jnp.pad(band, ((0, pad), (0, 0))), k)
+        xb = jnp.pad(x, ((k, pad + k), (0, 0))).reshape(nb + 2, k, -1)
+        xw = jnp.concatenate([xb[:-2], xb[1:-1], xb[2:]], axis=1)  # (nb, 3K, R)
+        y = jnp.sum(win[..., None] * xw[:, None], axis=2).reshape(nb * k, -1)[:n]
     return y[:, 0] if squeeze else y
 
 
@@ -189,42 +215,41 @@ class BlockTridiag:
         return (self.d, self.e, self.f, self.b_cpl, self.c_cpl), self.n
 
 
-def band_to_block_tridiag(band: jax.Array, k: int, p: int) -> BlockTridiag:
-    """Split a banded system into P partitions of block-tridiagonal (K x K)."""
-    n = band.shape[0]
+def band_to_block_tridiag(band, k: int, p: int, xp=jnp) -> BlockTridiag:
+    """Split a banded system into P partitions of block-tridiagonal (K x K).
+
+    ``xp`` is the array module: ``jnp`` (default; traceable under jit and
+    vmap) or ``np``, which builds the blocks in host memory -- the sharded
+    path places them on the mesh from there, so no device ever holds the
+    whole system.  The split is pure data movement (:func:`_block_windows`).
+    """
+    band = xp.asarray(band)
+    n, w = band.shape
     ni = padded_partition_size(n, p, k)
     n_pad = ni * p
-    band_p, _ = pad_banded(band, jnp.zeros((n,), band.dtype), n_pad)
-    dense_rows = band_p  # (n_pad, 2k+1)
     m = ni // k
+    if n_pad > n:  # identity rows up to the padded size
+        eye_row = (xp.arange(w) == (w - 1) // 2).astype(band.dtype)
+        band = xp.concatenate(
+            [band, xp.broadcast_to(eye_row, (n_pad - n, w))], axis=0
+        )
 
-    # Scatter band rows into a per-row (3K) window aligned to block columns:
-    # row r (global) belongs to block row br = r // k, with offset o = r % k.
-    # Window covers columns [br*k - k, br*k + 2k).  Band column j maps to
-    # global col c = r - k + j  ->  window index  c - (br*k - k) = o + j.
-    w = 2 * k + 1
-    win = jnp.zeros((n_pad, 3 * k), band.dtype)
-    r = jnp.arange(n_pad)
-    o = r % k
-    for j in range(w):
-        c = r - k + j
-        valid = (c >= 0) & (c < n_pad)
-        win = win.at[r, o + j].set(jnp.where(valid, dense_rows[:, j], 0.0))
-
-    win = win.reshape(p, m, k, 3 * k)
+    # Entries that fall off the matrix's first or last block column only
+    # reach blocks zeroed below.
+    win = _block_windows(band, k, xp).reshape(p, m, k, 3 * k)
     e = win[:, :, :, 0:k]
     d = win[:, :, :, k : 2 * k]
     f = win[:, :, :, 2 * k : 3 * k]
     # Zero out the cross-partition pieces: block row 0's sub-diag and block
     # row M-1's super-diag belong to coupling blocks, not to the partition.
-    e = e.at[:, 0].set(0.0)
-    f = f.at[:, m - 1].set(0.0)
+    row = xp.arange(m)[None, :, None, None]
+    e = xp.where(row == 0, xp.zeros((), band.dtype), e)
+    f = xp.where(row == m - 1, xp.zeros((), band.dtype), f)
 
     # Coupling blocks. B_i = A[part i bottom K rows, part i+1 top K cols]
-    # which is exactly win[f] of block row (i, M-1); C similarly.
-    win_full = win  # (p, m, k, 3k)
-    b_cpl = win_full[:-1, m - 1, :, 2 * k : 3 * k]  # (p-1, k, k)
-    c_cpl = win_full[1:, 0, :, 0:k]  # (p-1, k, k)
+    # which is exactly the F window of block row (i, M-1); C similarly.
+    b_cpl = win[:-1, m - 1, :, 2 * k : 3 * k]  # (p-1, k, k)
+    c_cpl = win[1:, 0, :, 0:k]  # (p-1, k, k)
     return BlockTridiag(d=d, e=e, f=f, b_cpl=b_cpl, c_cpl=c_cpl, n=n)
 
 

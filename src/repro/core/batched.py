@@ -478,12 +478,17 @@ def _solve_batch_many(
 
 def _factor_key(opts: SaPOptions) -> tuple:
     """The options that actually reach the factor stages -- tolerances and
-    Krylov knobs deliberately excluded so they never force a re-trace."""
+    Krylov knobs deliberately excluded so they never force a re-trace --
+    plus the kernel implementation, which ``REPRO_KERNEL_IMPL`` can change
+    between calls."""
+    from ..kernels.ops import default_impl  # lazy: repro.kernels imports core
+
     return (
         opts.boost_eps,
         opts.precond_dtype,
         opts.reduced_solver,
         opts.fused_factor,
+        default_impl(),
     )
 
 
@@ -495,7 +500,7 @@ def _factor_stages_fn(k: int, p: int, variant: str, opts_key: tuple):
     engine's repeated ``batch_factor`` calls hit the same traced
     executable instead of re-tracing every step.
     """
-    boost_eps, precond_dtype, reduced_solver, fused = opts_key
+    boost_eps, precond_dtype, reduced_solver, fused, impl = opts_key
     pdt = _precond_dtype(SaPOptions(precond_dtype=precond_dtype))
 
     def stages(band):
@@ -506,6 +511,7 @@ def _factor_stages_fn(k: int, p: int, variant: str, opts_key: tuple):
             variant=variant,
             boost_eps=boost_eps,
             precond_dtype=pdt,
+            impl=impl,
             reduced_solver=reduced_solver,
             fused=fused,
         )

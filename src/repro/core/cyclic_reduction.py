@@ -64,7 +64,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from .block_lu import DEFAULT_BOOST, gj_inverse
+from .block_lu import DEFAULT_BOOST, gj_inverse, mm
 
 
 def _next_pow2(m: int) -> int:
@@ -163,11 +163,11 @@ def bcr_reduce_level_ref(
     a_odd = _vinv(d[1::2], boost_eps)
     e_odd, f_odd = e[1::2], f[1::2]
     # E_0 = 0 kills the (clamped) i = 0 down-neighbor terms.
-    lo = e[0::2] @ _shift_dn(a_odd)  # E_{2i} inv(D_{2i-1})
-    hi = f[0::2] @ a_odd  # F_{2i} inv(D_{2i+1})
-    d_next = d[0::2] - lo @ _shift_dn(f_odd) - hi @ e_odd
-    e_next = -(lo @ _shift_dn(e_odd))
-    f_next = -(hi @ f_odd)
+    lo = mm(e[0::2], _shift_dn(a_odd))  # E_{2i} inv(D_{2i-1})
+    hi = mm(f[0::2], a_odd)  # F_{2i} inv(D_{2i+1})
+    d_next = d[0::2] - mm(lo, _shift_dn(f_odd)) - mm(hi, e_odd)
+    e_next = -mm(lo, _shift_dn(e_odd))
+    f_next = -mm(hi, f_odd)
     return BCRLevel(lo=lo, hi=hi, a_odd=a_odd, e_odd=e_odd, f_odd=f_odd), (
         d_next,
         e_next,
@@ -217,11 +217,11 @@ def bcr_solve(factors: BCRFactors, b: jax.Array) -> jax.Array:
     for lv in factors.levels:
         b_odd = b[1::2]
         saved_odd.append(b_odd)
-        b = b[0::2] - lv.lo @ _shift_dn(b_odd) - lv.hi @ b_odd
-    x = (factors.root_inv @ b[0])[None]
+        b = b[0::2] - mm(lv.lo, _shift_dn(b_odd)) - mm(lv.hi, b_odd)
+    x = mm(factors.root_inv, b[0])[None]
     for lv, b_odd in zip(reversed(factors.levels), reversed(saved_odd)):
         # F_odd of the chain tail is zero, killing the clamped up-neighbor.
-        x_odd = lv.a_odd @ (b_odd - lv.e_odd @ x - lv.f_odd @ _shift_up(x))
+        x_odd = mm(lv.a_odd, b_odd - mm(lv.e_odd, x) - mm(lv.f_odd, _shift_up(x)))
         x = jnp.stack([x, x_odd], axis=1).reshape(2 * x.shape[0], k, r)
     return x[:m]
 
@@ -293,11 +293,11 @@ def pcr_factor(
     for lev in range(n_levels):
         s = 1 << lev
         dinv = _vinv(d, boost_eps)
-        alpha = e @ shift_dn(dinv, s)
-        beta = f @ shift_up(dinv, s)
-        d = d - alpha @ shift_dn(f, s) - beta @ shift_up(e, s)
-        e_new = -(alpha @ shift_dn(e, s))
-        f_new = -(beta @ shift_up(f, s))
+        alpha = mm(e, shift_dn(dinv, s))
+        beta = mm(f, shift_up(dinv, s))
+        d = d - mm(alpha, shift_dn(f, s)) - mm(beta, shift_up(e, s))
+        e_new = -mm(alpha, shift_dn(e, s))
+        f_new = -mm(beta, shift_up(f, s))
         e, f = e_new, f_new
         alphas.append(alpha)
         betas.append(beta)
@@ -328,10 +328,10 @@ def pcr_solve(
         s = 1 << lev
         b = (
             b
-            - factors.alphas[:, lev] @ shift_dn(b, s)
-            - factors.betas[:, lev] @ shift_up(b, s)
+            - mm(factors.alphas[:, lev], shift_dn(b, s))
+            - mm(factors.betas[:, lev], shift_up(b, s))
         )
-    return factors.dinv @ b
+    return mm(factors.dinv, b)
 
 
 def resolve_reduced_solver(reduced_solver: str, m: int) -> str:

@@ -39,11 +39,12 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
-from .banded import diag_dominance_factor, pad_banded
-from .block_lu import DEFAULT_BOOST, btf_ref, btf_ul_ref, bts_ref, gj_inverse
+from .banded import band_to_block_tridiag, diag_dominance_factor
+from .block_lu import DEFAULT_BOOST, btf_ref, btf_ul_ref, bts_ref, gj_inverse, mm
 from .cyclic_reduction import pcr_factor, pcr_n_levels, pcr_solve
 from .krylov import bicgstab2
 from .sap import SaPSolveResult, resolve_variant
@@ -64,14 +65,14 @@ def n_devices(mesh) -> int:
 
 def _shift_from_next(x, axes):
     """Each device receives the value owned by device (idx+1); last gets 0."""
-    n = axis_size(axes)
+    n = jax.lax.axis_size(axes)
     perm = [(i + 1, i) for i in range(n - 1)]
     return jax.lax.ppermute(x, axes, perm)
 
 
 def _shift_from_prev(x, axes):
     """Each device receives the value owned by device (idx-1); first gets 0."""
-    n = axis_size(axes)
+    n = jax.lax.axis_size(axes)
     perm = [(i, i + 1) for i in range(n - 1)]
     return jax.lax.ppermute(x, axes, perm)
 
@@ -80,7 +81,7 @@ def _from_prev_by(x, dq, axes):
     """Receive the block owned by the device ``dq`` positions before."""
     if dq == 0:
         return x
-    n = axis_size(axes)
+    n = jax.lax.axis_size(axes)
     perm = [(i, i + dq) for i in range(n - dq)]
     return jax.lax.ppermute(x, axes, perm)
 
@@ -88,7 +89,7 @@ def _from_prev_by(x, dq, axes):
 def _from_next_by(x, dq, axes):
     if dq == 0:
         return x
-    n = axis_size(axes)
+    n = jax.lax.axis_size(axes)
     perm = [(i, i - dq) for i in range(dq, n)]
     return jax.lax.ppermute(x, axes, perm)
 
@@ -121,7 +122,7 @@ def _flat_device_index(axes):
     """Row-major flattened index of this device over the mesh axes."""
     idx = jnp.int32(0)
     for ax in axes:
-        idx = idx * axis_size(ax) + jax.lax.axis_index(ax)
+        idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     return idx
 
 
@@ -152,16 +153,16 @@ def _local_factor_c(d, e, f, b_next, c_prev, boost_eps, axes):
     """Runs per device.  d/e/f: (p_loc, M, K, K); couplings per partition."""
     lu = btf_ref(d, e, f, boost_eps)
     # right-spike bottoms (for interface owned by this partition)
-    v_bot = lu.sinv[:, -1] @ b_next  # (p_loc, K, K)
+    v_bot = mm(lu.sinv[:, -1], b_next)  # (p_loc, K, K)
     # left-spike tops of *this* partition (for the interface owned by prev)
     ul = btf_ul_ref(d, e, f, boost_eps)
-    w_top = (ul.sinv[:, -1] @ c_prev[..., ::-1, :])[..., ::-1, :]
+    w_top = mm(ul.sinv[:, -1], c_prev[..., ::-1, :])[..., ::-1, :]
     # align W^(t) of partition i+1 at interface index i
     w_next = jnp.concatenate(
         [w_top[1:], _shift_from_next(w_top[:1], axes)], axis=0
     )
     eye = jnp.eye(d.shape[-1], dtype=d.dtype)
-    rbar = eye - w_next @ v_bot
+    rbar = eye - mm(w_next, v_bot)
     rbar_inv = jax.vmap(lambda a: gj_inverse(a, boost_eps))(rbar)
     return lu, v_bot, w_next, rbar_inv
 
@@ -175,16 +176,16 @@ def _local_apply_c(state, b_next, c_prev, rb, axes):
     g_top_next = jnp.concatenate(
         [g_top[1:], _shift_from_next(g_top[:1], axes)], axis=0
     )
-    rhs = g_top_next - w_next @ g_bot
-    xt_top = rbar_inv @ rhs  # x~ for top of partition i+1
-    xt_bot = g_bot - v_bot @ xt_top  # x~ for bottom of partition i
+    rhs = g_top_next - mm(w_next, g_bot)
+    xt_top = mm(rbar_inv, rhs)  # x~ for top of partition i+1
+    xt_bot = g_bot - mm(v_bot, xt_top)  # x~ for bottom of partition i
     # partition j needs: bottom corr B_j xt_top[j] (local); top corr
     # C_j xt_bot[j-1] (shift up)
     xt_bot_prev = jnp.concatenate(
         [_shift_from_prev(xt_bot[-1:], axes), xt_bot[:-1]], axis=0
     )
-    rb2 = rb.at[:, -1].add(-(b_next @ xt_top))
-    rb2 = rb2.at[:, 0].add(-(c_prev @ xt_bot_prev))
+    rb2 = rb.at[:, -1].add(-mm(b_next, xt_top))
+    rb2 = rb2.at[:, 0].add(-mm(c_prev, xt_bot_prev))
     return bts_ref(lu, rb2)
 
 
@@ -264,8 +265,8 @@ def _local_apply_e(state, b_next, c_prev, rb, axes):
     xt_bot_prev = jnp.concatenate(
         [_shift_from_prev(xt_bot[-1:], axes), xt_bot[:-1]], axis=0
     )
-    rb2 = rb.at[:, -1].add(-(b_next @ xt_top))
-    rb2 = rb2.at[:, 0].add(-(c_prev @ xt_bot_prev))
+    rb2 = rb.at[:, -1].add(-mm(b_next, xt_top))
+    rb2 = rb2.at[:, 0].add(-mm(c_prev, xt_bot_prev))
     return bts_ref(lu, rb2)
 
 
@@ -298,7 +299,7 @@ def dist_diag_dominance_factor(mesh, band_p: jax.Array) -> jax.Array:
     def local_d(rows):
         return jax.lax.pmin(diag_dominance_factor(rows), axes)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_d,
         mesh=mesh,
         in_specs=(P(axes, None),),
@@ -311,6 +312,17 @@ def dist_diag_dominance_factor(mesh, band_p: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 # Builder
 # ---------------------------------------------------------------------------
+
+
+def _host_pad(band, n_pad: int) -> np.ndarray:
+    """Identity-pad (N, 2K+1) band rows to ``n_pad`` rows in host memory,
+    in the dtype JAX would give the band (f32 unless x64 is on)."""
+    band = np.asarray(band)
+    n, w = band.shape
+    out = np.zeros((n_pad, w), jax.dtypes.canonicalize_dtype(band.dtype))
+    out[:n] = band
+    out[n:, (w - 1) // 2] = 1.0
+    return out
 
 
 def build_dist_sap(
@@ -346,42 +358,41 @@ def build_dist_sap(
 
     variant_requested = variant
     d_factor = None
+    part_spec = P(axes)  # flattened over all axes
+    place = lambda x: jax.device_put(x, NamedSharding(mesh, part_spec))
+
     if variant == "auto":
         if band is None:
             raise ValueError(
                 'variant="auto" needs the band rows to estimate diagonal '
                 "dominance; pass band=(N, 2K+1) storage to build_dist_sap"
             )
-        band_p, _ = pad_banded(
-            jnp.asarray(band), jnp.zeros((n,), jnp.asarray(band).dtype), n_pad
-        )
+        band_p = place(_host_pad(band, n_pad))
         with mesh:
             d_factor = float(dist_diag_dominance_factor(mesh, band_p))
         variant = resolve_variant("auto", d_factor)
 
-    part_spec = P(axes)  # flattened over all axes
-
     def shard_band(band, b):
-        """Host-side: pad + compute block-tridiag global arrays (numpy path,
-        for examples/tests; the dry-run uses ShapeDtypeStructs instead)."""
-        from .banded import band_to_block_tridiag
-
-        band_p, b_p = pad_banded(jnp.asarray(band), jnp.asarray(b), n_pad)
-        bt = band_to_block_tridiag(band_p, k, p_total)
-        b_next = jnp.concatenate(
-            [bt.b_cpl, jnp.zeros((1, k, k), bt.b_cpl.dtype)], axis=0
-        )
-        c_prev = jnp.concatenate(
-            [jnp.zeros((1, k, k), bt.c_cpl.dtype), bt.c_cpl], axis=0
-        )
+        """Pad the system and split it into partition blocks in host memory
+        (numpy), then place every array on the mesh sharded by partition:
+        no device ever holds the whole band or the whole block arrays.
+        (The dry-run passes ShapeDtypeStructs instead.)"""
+        band_p = _host_pad(band, n_pad)
+        b = np.asarray(b)
+        b_p = np.zeros((n_pad,) + b.shape[1:], jax.dtypes.canonicalize_dtype(b.dtype))
+        b_p[: b.shape[0]] = b
+        bt = band_to_block_tridiag(band_p, k, p_total, xp=np)
+        zero = np.zeros((1, k, k), bt.b_cpl.dtype)
+        pdt = np.dtype(precond_dtype)
         parts = {
-            "d": bt.d.astype(precond_dtype),
-            "e": bt.e.astype(precond_dtype),
-            "f": bt.f.astype(precond_dtype),
-            "b_next": b_next.astype(precond_dtype),
-            "c_prev": c_prev.astype(precond_dtype),
+            "d": bt.d,
+            "e": bt.e,
+            "f": bt.f,
+            "b_next": np.concatenate([bt.b_cpl, zero], axis=0),
+            "c_prev": np.concatenate([zero, bt.c_cpl], axis=0),
         }
-        return band_p, b_p, parts
+        parts = {name: place(x.astype(pdt)) for name, x in parts.items()}
+        return place(band_p), place(b_p), parts
 
     # ---- shard_mapped closures ---------------------------------------------
     # Every variant's factor returns an opaque per-device state pytree and
@@ -407,7 +418,7 @@ def build_dist_sap(
         def apply_local(state, b_next, c_prev, rb):
             return bts_ref(state[0], rb)
 
-    fac_fn = shard_map(
+    fac_fn = jax.shard_map(
         fac_local,
         mesh=mesh,
         in_specs=(part_spec,) * 5,
@@ -415,7 +426,7 @@ def build_dist_sap(
         check_vma=False,
     )
 
-    apply_fn = shard_map(
+    apply_fn = jax.shard_map(
         apply_local,
         mesh=mesh,
         in_specs=(part_spec,) * 4,
@@ -423,7 +434,7 @@ def build_dist_sap(
         check_vma=False,
     )
 
-    mv_fn = shard_map(
+    mv_fn = jax.shard_map(
         lambda band, x: _local_matvec(band, x, k, axes),
         mesh=mesh,
         in_specs=(part_spec, part_spec),

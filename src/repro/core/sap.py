@@ -391,10 +391,15 @@ def factor(pl: SaPPlan) -> SaPFactorization:
     Device-side and done once; the returned handle is reusable across any
     number of ``solve`` / ``solve_many`` calls and jit boundaries.
     ``variant="auto"`` is resolved here from the planned preconditioner
-    band's degree of diagonal dominance (C for d >= 1, else E).
+    band's degree of diagonal dominance (C for d >= 1, else E).  The
+    kernel implementation is :func:`repro.kernels.ops.default_impl`:
+    the compiled Pallas kernels on a TPU, the jnp references elsewhere.
     """
+    from ..kernels.ops import default_impl  # lazy: repro.kernels imports core
+
     opts = pl.opts
-    with span("factor", n=pl.n, k=pl.k, p=opts.p) as sp:
+    impl = default_impl()
+    with span("factor", n=pl.n, k=pl.k, p=opts.p, impl=impl) as sp:
         d_factor = diag_dominance_factor(pl.band_pc)
         variant = resolve_variant(opts.variant, float(d_factor))
         sp.annotate(variant=variant, d_factor=float(d_factor))
@@ -405,6 +410,7 @@ def factor(pl: SaPPlan) -> SaPFactorization:
             variant=variant,
             boost_eps=opts.boost_eps,
             precond_dtype=_precond_dtype(opts),
+            impl=impl,
             reduced_solver=opts.reduced_solver,
             fused=opts.fused_factor,
         )

@@ -66,6 +66,7 @@ from .block_lu import (
     bts_ref,
     fused_factor_spike_ref,
     gj_inverse,
+    mm,
 )
 from ..obs.trace import span
 from .cyclic_reduction import (
@@ -209,13 +210,13 @@ def _apply_coupled(pc: SaPPreconditioner, rb: jax.Array) -> jax.Array:
     g_bot = g[:, -1]  # (P, K, R)
 
     # 2) reduced-system correction per interface i = 0..P-2   (eq. 2.9)
-    rhs = g_top[1:] - pc.w_top @ g_bot[:-1]  # (P-1, K, R)
-    xt_top = pc.rbar_inv @ rhs  # xt_{i+1}^(t)
-    xt_bot = g_bot[:-1] - pc.v_bot @ xt_top  # xt_i^(b)
+    rhs = g_top[1:] - mm(pc.w_top, g_bot[:-1])  # (P-1, K, R)
+    xt_top = mm(pc.rbar_inv, rhs)  # xt_{i+1}^(t)
+    xt_bot = g_bot[:-1] - mm(pc.v_bot, xt_top)  # xt_i^(b)
 
     # 3) final solves (eq. 2.10): subtract coupling contributions
-    top_corr = pc.c_cpl @ xt_bot  # into partitions 1..P-1, top block
-    bot_corr = pc.b_cpl @ xt_top  # into partitions 0..P-2, bottom block
+    top_corr = mm(pc.c_cpl, xt_bot)  # into partitions 1..P-1, top block
+    bot_corr = mm(pc.b_cpl, xt_top)  # into partitions 0..P-2, bottom block
     rb2 = rb
     rb2 = rb2.at[1:, 0].add(-top_corr)
     rb2 = rb2.at[:-1, -1].add(-bot_corr)
@@ -239,8 +240,8 @@ def _apply_exact(pc: SaPPreconditioner, rb: jax.Array) -> jax.Array:
     xt_top = y[:, pc.k :]  # x_{i+1}^(t), i = 0..P-2
 
     # 3) final solves (eq. 2.10), now with exact interface values
-    rb2 = rb.at[1:, 0].add(-(pc.c_cpl @ xt_bot))
-    rb2 = rb2.at[:-1, -1].add(-(pc.b_cpl @ xt_top))
+    rb2 = rb.at[1:, 0].add(-mm(pc.c_cpl, xt_bot))
+    rb2 = rb2.at[:-1, -1].add(-mm(pc.b_cpl, xt_top))
     return _bts(pc.lu, rb2, pc.impl)
 
 
@@ -359,11 +360,11 @@ def build_preconditioner(
             with span("factor.spike", variant=variant, mode=spike_mode) as sp:
                 if variant == "C" and spike_mode == "ul":
                     # V_i^(b) = Sinv_i[M-1] @ B_i  for i = 0..P-2
-                    v_bot = lu.sinv[:-1, -1] @ b_cpl
+                    v_bot = mm(lu.sinv[:-1, -1], b_cpl)
                     # W_{i+1}^(t) from the UL factorization of partitions
                     # 1..P-1
                     ul = btf_ul_ref(d, e, f, boost_eps)
-                    w_top = _flip_rows(ul.sinv[1:, -1] @ _flip_rows(c_cpl))
+                    w_top = _flip_rows(mm(ul.sinv[1:, -1], _flip_rows(c_cpl)))
                 else:
                     # whole right spikes: A_i V_i = [0;..;B_i], keep corners
                     rhs_b = jnp.zeros((bt.p, bt.m, bt.k, bt.k), precond_dtype)
@@ -381,7 +382,7 @@ def build_preconditioner(
         if variant == "C":
             with span("factor.reduced", solver="truncated") as sp:
                 eye = jnp.eye(bt.k, dtype=precond_dtype)
-                rbar = eye - w_top @ v_bot
+                rbar = eye - mm(w_top, v_bot)
                 rbar_inv = jax.vmap(lambda a: gj_inverse(a, boost_eps))(rbar)
                 sp.sync(rbar_inv)
         else:

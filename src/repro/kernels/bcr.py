@@ -33,10 +33,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
-from repro.core.block_lu import DEFAULT_BOOST, gj_inverse
+from repro.core.block_lu import DEFAULT_BOOST, gj_inverse, mm
 from repro.core.cyclic_reduction import BCRFactors, BCRLevel, pad_chain
 
 
@@ -66,12 +65,11 @@ def _reduce_kernel(
     a_cur = ac_ref[0].astype(jnp.float32)
     a_prev = ap_ref[0].astype(jnp.float32)
 
-    dot = lambda x, y: jnp.dot(x, y, preferred_element_type=jnp.float32)
-    lo = dot(e, a_prev)
-    hi = dot(f, a_cur)
-    dn_ref[0] = (d - dot(lo, f_prev) - dot(hi, e_next)).astype(dn_ref.dtype)
-    eo_ref[0] = (-dot(lo, e_prev)).astype(eo_ref.dtype)
-    fo_ref[0] = (-dot(hi, f_next)).astype(fo_ref.dtype)
+    lo = mm(e, a_prev)
+    hi = mm(f, a_cur)
+    dn_ref[0] = (d - mm(lo, f_prev) - mm(hi, e_next)).astype(dn_ref.dtype)
+    eo_ref[0] = (-mm(lo, e_prev)).astype(eo_ref.dtype)
+    fo_ref[0] = (-mm(hi, f_next)).astype(fo_ref.dtype)
     lo_ref[0] = lo.astype(lo_ref.dtype)
     hi_ref[0] = hi.astype(hi_ref.dtype)
 
@@ -82,8 +80,7 @@ def _rhs_reduce_kernel(lo_ref, hi_ref, b_ref, bp_ref, bn_ref, out_ref):
     b = b_ref[0].astype(jnp.float32)
     b_prev = bp_ref[0].astype(jnp.float32)  # b_{2i-1}, clamped (lo_0 = 0)
     b_next = bn_ref[0].astype(jnp.float32)  # b_{2i+1}
-    dot = lambda x, y: jnp.dot(x, y, preferred_element_type=jnp.float32)
-    out_ref[0] = (b - dot(lo, b_prev) - dot(hi, b_next)).astype(out_ref.dtype)
+    out_ref[0] = (b - mm(lo, b_prev) - mm(hi, b_next)).astype(out_ref.dtype)
 
 
 def _backsub_kernel(a_ref, e_ref, f_ref, b_ref, x_ref, xn_ref, out_ref):
@@ -94,8 +91,7 @@ def _backsub_kernel(a_ref, e_ref, f_ref, b_ref, x_ref, xn_ref, out_ref):
     b = b_ref[0].astype(jnp.float32)
     x_even = x_ref[0].astype(jnp.float32)
     x_next = xn_ref[0].astype(jnp.float32)  # x_{2i+2}, clamped (f_odd end = 0)
-    dot = lambda x, y: jnp.dot(x, y, preferred_element_type=jnp.float32)
-    x_odd = dot(a, b - dot(e, x_even) - dot(f, x_next))
+    x_odd = mm(a, b - mm(e, x_even) - mm(f, x_next))
     out_ref[0] = x_even.astype(out_ref.dtype)
     out_ref[1] = x_odd.astype(out_ref.dtype)
 
@@ -106,7 +102,7 @@ def _specs(k, last, *idx_maps):
     ]
 
 
-_PARALLEL = CompilerParams(dimension_semantics=("parallel",))
+_PARALLEL = pltpu.CompilerParams(dimension_semantics=("parallel",))
 
 
 # ---------------------------------------------------------------------------
@@ -200,7 +196,8 @@ def bcr_factor_pallas(
     e: jax.Array,
     f: jax.Array,
     boost_eps: float = DEFAULT_BOOST,
-    interpret: bool = True,
+    *,
+    interpret: bool,
     lane_pad: bool | None = None,
 ) -> BCRFactors:
     """Factor one chain (M, K, K) in log2(M) kernel-level rounds.
@@ -227,7 +224,7 @@ def bcr_factor_pallas(
 
 @functools.partial(jax.jit, static_argnames=("interpret", "lane_pad"))
 def bcr_solve_pallas(
-    factors: BCRFactors, b: jax.Array, interpret: bool = True,
+    factors: BCRFactors, b: jax.Array, *, interpret: bool,
     lane_pad: bool | None = None,
 ) -> jax.Array:
     """Solve one factored chain: b (M, K, R) -> x (M, K, R).
@@ -273,7 +270,7 @@ def bcr_solve_pallas(
             compiler_params=_PARALLEL,
         )(lv.lo, lv.hi, b, b, b)
 
-    x = (factors.root_inv @ b[0])[None]
+    x = mm(factors.root_inv, b[0])[None]
     for lv, b_odd in zip(reversed(factors.levels), reversed(saved_odd)):
         m2 = x.shape[0]
         x = pl.pallas_call(

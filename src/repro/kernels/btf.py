@@ -32,9 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
-from repro.core.block_lu import DEFAULT_BOOST, gj_inverse
+from repro.core.block_lu import DEFAULT_BOOST, gj_inverse, mm
 
 
 def _btf_kernel(d_ref, e_ref, f_prev_ref, sinv_ref, l_ref, carry, *, boost_eps):
@@ -53,8 +51,8 @@ def _btf_kernel(d_ref, e_ref, f_prev_ref, sinv_ref, l_ref, carry, *, boost_eps):
     def _rest():
         e = e_ref[0, 0].astype(jnp.float32)
         f_prev = f_prev_ref[0, 0].astype(jnp.float32)
-        lj = jnp.dot(e, carry[...], preferred_element_type=jnp.float32)
-        sj = d - jnp.dot(lj, f_prev, preferred_element_type=jnp.float32)
+        lj = mm(e, carry[...])
+        sj = d - mm(lj, f_prev)
         sinv = gj_inverse(sj, boost_eps)
         carry[...] = sinv
         sinv_ref[0, 0] = sinv.astype(sinv_ref.dtype)
@@ -67,7 +65,8 @@ def btf_pallas(
     e: jax.Array,
     f: jax.Array,
     boost_eps: float = DEFAULT_BOOST,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """Factor all partitions.  d/e/f: (P, M, K, K) -> (sinv, l) same shape."""
     p, m, k, _ = d.shape
@@ -86,7 +85,7 @@ def btf_pallas(
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((k, k), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(d, e, f)

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
+from repro.core.block_lu import mm
 
 
 def _fwd_kernel(l_ref, b_ref, y_ref, carry):
@@ -36,7 +36,7 @@ def _fwd_kernel(l_ref, b_ref, y_ref, carry):
     @pl.when(j > 0)
     def _rest():
         l = l_ref[0, 0].astype(jnp.float32)
-        y = b - jnp.dot(l, carry[...], preferred_element_type=jnp.float32)
+        y = b - mm(l, carry[...])
         carry[...] = y
         y_ref[0, 0] = y.astype(y_ref.dtype)
 
@@ -48,15 +48,15 @@ def _bwd_kernel(sinv_ref, f_ref, y_ref, x_ref, carry):
 
     @pl.when(jr == 0)
     def _first():
-        x = jnp.dot(sinv, y, preferred_element_type=jnp.float32)
+        x = mm(sinv, y)
         carry[...] = x
         x_ref[0, 0] = x.astype(x_ref.dtype)
 
     @pl.when(jr > 0)
     def _rest():
         f = f_ref[0, 0].astype(jnp.float32)
-        rhs = y - jnp.dot(f, carry[...], preferred_element_type=jnp.float32)
-        x = jnp.dot(sinv, rhs, preferred_element_type=jnp.float32)
+        rhs = y - mm(f, carry[...])
+        x = mm(sinv, rhs)
         carry[...] = x
         x_ref[0, 0] = x.astype(x_ref.dtype)
 
@@ -67,7 +67,8 @@ def bts_pallas(
     l: jax.Array,
     f: jax.Array,
     b: jax.Array,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """Solve D x = b for all partitions.
 
@@ -88,7 +89,7 @@ def bts_pallas(
         out_shape=jax.ShapeDtypeStruct(b.shape, b.dtype),
         scratch_shapes=[pltpu.VMEM((k, r), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(l, b)
@@ -103,7 +104,7 @@ def bts_pallas(
         out_shape=jax.ShapeDtypeStruct(b.shape, b.dtype),
         scratch_shapes=[pltpu.VMEM((k, r), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(sinv, f, y)

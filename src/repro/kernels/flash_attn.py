@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -105,7 +103,8 @@ def flash_attention_pallas(
     window: int | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     b, hq, tq, d = q.shape
     hk, tk = k.shape[1], k.shape[2]
@@ -145,7 +144,7 @@ def flash_attention_pallas(
             pltpu.VMEM((g * block_q, d), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(qg, kf, vf)

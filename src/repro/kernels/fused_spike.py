@@ -24,7 +24,12 @@ w_bot) are emitted into constant-index output blocks (flushed once at the
 end of each partition's sweep).  The reversed-chain blocks are read
 through reversed BlockSpec index maps (the ``kernels/bts.py`` backward
 idiom) and flipped in VMEM, so no reversed copy of the chain exists in
-HBM either.
+HBM either.  Mosaic has no in-kernel reversal (``rev``), so a flip is a
+product with the exchange matrix J (ones on the anti-diagonal).  Like
+every matmul of the solver it runs at ``precision=HIGHEST``
+(:func:`repro.core.block_lu.mm`): the TPU's DEFAULT f32 matmul rounds
+its operands through bf16, HIGHEST carries all 24 bits, and a product
+with a permutation matrix is then an exact copy.
 
 Oracle: :func:`repro.core.block_lu.fused_factor_spike_padded_ref`, the
 op-for-op scan formulation -- interpret mode matches it bit-for-bit.
@@ -39,9 +44,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
+from repro.core.block_lu import DEFAULT_BOOST, gj_inverse, mm
 
-from repro.core.block_lu import DEFAULT_BOOST, gj_inverse
+
+def _exchange(k: int) -> jax.Array:
+    """The (K, K) exchange matrix J: ``J @ x`` reverses the rows of x."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (k, k), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (k, k), 1)
+    return (rows + cols == k - 1).astype(jnp.float32)
 
 
 def _fused_kernel(
@@ -55,8 +65,11 @@ def _fused_kernel(
     m = pl.num_programs(1)
 
     d = d_ref[0, 0].astype(jnp.float32)
+    jx = _exchange(d.shape[0])
+    fliprows = lambda x: mm(jx, x)
+    flip2 = lambda x: mm(mm(jx, x), jx)
     # reversed-chain blocks, flipped in VMEM (flip_block_tridiag values)
-    d_r = d_rev_ref[0, 0].astype(jnp.float32)[::-1, ::-1]
+    d_r = flip2(d_rev_ref[0, 0].astype(jnp.float32))
     bq = bq_ref[0].astype(jnp.float32)
     cq = cq_ref[0].astype(jnp.float32)
 
@@ -68,43 +81,35 @@ def _fused_kernel(
         l_ref[0, 0] = jnp.zeros_like(d).astype(l_ref.dtype)
         c_ul[...] = gj_inverse(d_r, boost_eps)
         c_w[...] = cq
-        c_v[...] = bq[::-1, :]
+        c_v[...] = fliprows(bq)
 
     @pl.when(j > 0)
     def _rest():
         e = e_ref[0, 0].astype(jnp.float32)
         f_prev = f_prev_ref[0, 0].astype(jnp.float32)
-        lj = jnp.dot(e, c_lu[...], preferred_element_type=jnp.float32)
-        sj = d - jnp.dot(lj, f_prev, preferred_element_type=jnp.float32)
+        lj = mm(e, c_lu[...])
+        sj = d - mm(lj, f_prev)
         sinv = gj_inverse(sj, boost_eps)
         c_lu[...] = sinv
         sinv_ref[0, 0] = sinv.astype(sinv_ref.dtype)
         l_ref[0, 0] = lj.astype(l_ref.dtype)
-        c_w[...] = -jnp.dot(lj, c_w[...], preferred_element_type=jnp.float32)
+        c_w[...] = -mm(lj, c_w[...])
 
-        e_r = f_rev_ref[0, 0].astype(jnp.float32)[::-1, ::-1]
-        f_r_prev = e_revp1_ref[0, 0].astype(jnp.float32)[::-1, ::-1]
-        l_ul = jnp.dot(e_r, c_ul[...], preferred_element_type=jnp.float32)
-        s_ul = d_r - jnp.dot(l_ul, f_r_prev, preferred_element_type=jnp.float32)
+        e_r = flip2(f_rev_ref[0, 0].astype(jnp.float32))
+        f_r_prev = flip2(e_revp1_ref[0, 0].astype(jnp.float32))
+        l_ul = mm(e_r, c_ul[...])
+        s_ul = d_r - mm(l_ul, f_r_prev)
         c_ul[...] = gj_inverse(s_ul, boost_eps)
-        c_v[...] = -jnp.dot(l_ul, c_v[...], preferred_element_type=jnp.float32)
+        c_v[...] = -mm(l_ul, c_v[...])
 
     @pl.when(j == m - 1)
     def _emit():
         sinv = c_lu[...]
         sinv_ul = c_ul[...]
-        vb_ref[0] = jnp.dot(
-            sinv, bq, preferred_element_type=jnp.float32
-        ).astype(vb_ref.dtype)
-        wb_ref[0] = jnp.dot(
-            sinv, c_w[...], preferred_element_type=jnp.float32
-        ).astype(wb_ref.dtype)
-        wt_ref[0] = jnp.dot(
-            sinv_ul, cq[::-1, :], preferred_element_type=jnp.float32
-        )[::-1, :].astype(wt_ref.dtype)
-        vt_ref[0] = jnp.dot(
-            sinv_ul, c_v[...], preferred_element_type=jnp.float32
-        )[::-1, :].astype(vt_ref.dtype)
+        vb_ref[0] = mm(sinv, bq).astype(vb_ref.dtype)
+        wb_ref[0] = mm(sinv, c_w[...]).astype(wb_ref.dtype)
+        wt_ref[0] = fliprows(mm(sinv_ul, fliprows(cq))).astype(wt_ref.dtype)
+        vt_ref[0] = fliprows(mm(sinv_ul, c_v[...])).astype(vt_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("boost_eps", "interpret"))
@@ -115,7 +120,8 @@ def fused_factor_spike_pallas(
     bq: jax.Array,
     cq: jax.Array,
     boost_eps: float = DEFAULT_BOOST,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """Fused factor + spike corners for all partitions.
 
@@ -159,7 +165,7 @@ def fused_factor_spike_pallas(
             pltpu.VMEM((k, k), jnp.float32),  # c_v
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(d, e, f, d, f, e, bq, cq)

@@ -5,9 +5,9 @@
   * "interpret" -- Pallas kernel executed in interpret mode (CPU-validated)
   * "pallas"    -- compiled Pallas TPU kernel (the production path)
 
-The default comes from the env var ``REPRO_KERNEL_IMPL`` and falls back to
-"jnp" when no TPU is present, "pallas" otherwise, so the same model code
-runs everywhere.
+The default comes from the env var ``REPRO_KERNEL_IMPL`` when it is set
+(an unknown value raises); otherwise it is "pallas" when JAX's first
+device is a TPU and "jnp" on any other backend.
 
 All three paths share :func:`repro.core.block_lu.gj_inverse` and with it
 the structural-zero pivot exemption: exactly-zero block rows (identity
@@ -41,21 +41,28 @@ from .ssd_chunk import ssd_pallas
 from .wkv_chunk import wkv6_pallas
 
 
+IMPLS = ("jnp", "interpret", "pallas")
+
+
 def default_impl() -> str:
     """Kernel backend: REPRO_KERNEL_IMPL if set, else "pallas" on TPU, "jnp"."""
     env = os.environ.get("REPRO_KERNEL_IMPL")
     if env:
+        if env not in IMPLS:
+            raise ValueError(
+                f"REPRO_KERNEL_IMPL={env!r} is not one of {IMPLS}"
+            )
         return env
-    try:
-        if jax.devices()[0].platform == "tpu":
-            return "pallas"
-    except Exception:  # pragma: no cover
-        pass
-    return "jnp"
+    return "pallas" if jax.devices()[0].platform == "tpu" else "jnp"
 
 
 def _interpret(impl: str) -> bool:
-    return impl != "pallas"
+    """Pallas execution mode for a kernel impl: interpreted or compiled."""
+    if impl == "interpret":
+        return True
+    if impl == "pallas":
+        return False
+    raise ValueError(f"no Pallas kernel runs under impl={impl!r}")
 
 
 # ---------------------------------------------------------------------------

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 
 def _ssd_kernel(x_ref, b_ref, c_ref, la_ref, s0_ref, y_ref, sout_ref, s, *, chunk):
     nc = pl.program_id(1)
@@ -68,7 +66,8 @@ def ssd_pallas(
     loga: jax.Array,  # (BH, T)
     state: jax.Array,  # (BH, N, P)
     chunk: int = 64,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     bh, t, p = x.shape
     n = b.shape[-1]
@@ -89,7 +88,7 @@ def ssd_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(x, b, c, loga, state)

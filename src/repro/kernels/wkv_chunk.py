@@ -32,8 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 
 def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, sout_ref, s, *, chunk):
     nc = pl.program_id(1)
@@ -87,7 +85,8 @@ def wkv6_pallas(
     u: jax.Array,  # (BH, D)
     state: jax.Array,  # (BH, D, D)
     chunk: int = 64,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     bh, t, d = r.shape
     assert t % chunk == 0, f"T={t} not divisible by chunk={chunk}"
@@ -106,7 +105,7 @@ def wkv6_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(r, k, v, logw, u, state)

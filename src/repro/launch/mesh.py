@@ -13,19 +13,6 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` kwargs when this jax has them (>= 0.5), else nothing.
-
-    Older jax releases have neither ``jax.sharding.AxisType`` nor the
-    ``axis_types`` parameter on ``jax.make_mesh``; Auto is their only
-    behavior anyway, so omitting the kwarg is semantically identical.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
@@ -38,9 +25,13 @@ def make_production_mesh(*, multi_pod: bool = False):
             shape = (2, 2, ndev // 4)
         else:
             shape = (2, ndev // 2)
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_test_mesh(shape=(2, 4), axes=("data", "model")):
     """Small mesh for unit tests (requires xla_force_host_platform_device_count)."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
 from repro.kernels import ops as kops
 
 
@@ -38,7 +37,7 @@ def _prefix_chain(s_loc, ltot_exp, axes):
     ltot_exp: per-shard total decay, broadcastable to s_loc
     Returns r = sum_{j < i} (prod_{j < l < i} D_l) s_j   on shard i.
     """
-    n = axis_size(axes)
+    n = jax.lax.axis_size(axes)
     perm = [(i, i + 1) for i in range(n - 1)]  # send to next; first gets 0
 
     def step(_, r):
@@ -97,7 +96,7 @@ def sp_ssd(mesh, seq_axes=("data",)):
     spec_l = P(None, None, ax)
     spec_s = P(ax, None, None, None, None)  # per-shard states, stacked
     fn = partial(sp_ssd_local, axes=seq_axes)
-    return shard_map(
+    return jax.shard_map(
         lambda x, b, c, la: fn(x, b, c, la),
         mesh=mesh,
         in_specs=(spec_t, spec_t, spec_t, spec_l),
@@ -145,7 +144,7 @@ def sp_wkv6(mesh, seq_axes=("data",)):
     spec_u = P(None, None)
     spec_s = P(ax, None, None, None, None)
     fn = partial(sp_wkv6_local, axes=seq_axes)
-    return shard_map(
+    return jax.shard_map(
         lambda r, k, v, lw, u: fn(r, k, v, lw, u),
         mesh=mesh,
         in_specs=(spec_t, spec_t, spec_t, spec_t, spec_u),

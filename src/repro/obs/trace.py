@@ -54,10 +54,7 @@ __all__ = [
 
 def _under_jax_trace() -> bool:
     """True while JAX is abstractly tracing (jit/vmap/grad staging)."""
-    try:
-        return not jax.core.trace_state_clean()
-    except Exception:  # pragma: no cover - compat with future jax layouts
-        return False
+    return not jax.core.trace_ctx.is_top_level()
 
 
 def _jsonable(v: Any) -> Any:
@@ -158,20 +155,21 @@ class Span:
         return self
 
     def __exit__(self, *exc: Any) -> bool:
-        if self._pending is not None and self._tracer.device_sync:
-            try:
-                jax.block_until_ready(self._pending)
-            except Exception:
-                pass
-            self._pending = None
-        self.t1 = self._tracer.clock()
-        if self._ann is not None:
-            try:
-                self._ann.__exit__(*exc)
-            except Exception:  # pragma: no cover
-                pass
-            self._ann = None
-        self._tracer._finish(self)
+        # A device error surfacing at the sync propagates to the caller;
+        # the span still closes so the tracer's nesting stays intact.
+        try:
+            if self._pending is not None and self._tracer.device_sync:
+                pending, self._pending = self._pending, None
+                jax.block_until_ready(pending)
+        finally:
+            self.t1 = self._tracer.clock()
+            if self._ann is not None:
+                try:
+                    self._ann.__exit__(*exc)
+                except Exception:  # pragma: no cover
+                    pass
+                self._ann = None
+            self._tracer._finish(self)
         return False
 
     @property

@@ -48,6 +48,7 @@ import numpy as np
 
 from repro.core import batched
 from repro.core.sap import SaPOptions, resolve_variant
+from repro.kernels.ops import default_impl
 from repro.obs import cost as obs_cost
 from repro.obs.trace import span
 
@@ -135,11 +136,12 @@ def _opts_sig(opts: SaPOptions) -> tuple:
     Part of the LRU key: two factorizations of the same matrix under
     different variants (or precond dtypes, partition counts...) have
     different pytree structures and must never stack into one batch, so
-    they live under distinct cache entries.
+    they live under distinct cache entries.  The kernel implementation
+    is part of it too: ``REPRO_KERNEL_IMPL`` can change between calls.
     """
     return (opts.p, opts.variant, opts.reduced_solver,
             opts.precond_dtype, opts.boost_eps,
-            opts.fused_factor, opts.solver)
+            opts.fused_factor, opts.solver, default_impl())
 
 
 class SolverEngine:
