@@ -214,17 +214,18 @@ def plan_banded(band, opts: Optional[SaPOptions] = None) -> SaPPlan:
     itself is the preconditioner matrix.
     """
     opts = opts or SaPOptions()
-    op = band if isinstance(band, BandedOperator) else BandedOperator.from_band(band)
-    return SaPPlan(
-        op=op,
-        band_pc=op.band,
-        k=op.k,
-        n=op.n,
-        b_perm=None,
-        x_perm=None,
-        opts=opts,
-        info={"variant": opts.variant, "p": opts.p},
-    )
+    with span("plan"):
+        op = band if isinstance(band, BandedOperator) else BandedOperator.from_band(band)
+        return SaPPlan(
+            op=op,
+            band_pc=op.band,
+            k=op.k,
+            n=op.n,
+            b_perm=None,
+            x_perm=None,
+            opts=opts,
+            info={"variant": opts.variant, "p": opts.p},
+        )
 
 
 def plan(a, opts: Optional[SaPOptions] = None) -> SaPPlan:
@@ -400,9 +401,11 @@ def factor(pl: SaPPlan) -> SaPFactorization:
     opts = pl.opts
     impl = default_impl()
     with span("factor", n=pl.n, k=pl.k, p=opts.p, impl=impl) as sp:
-        d_factor = diag_dominance_factor(pl.band_pc)
-        variant = resolve_variant(opts.variant, float(d_factor))
-        sp.annotate(variant=variant, d_factor=float(d_factor))
+        with span("factor.dominance"):
+            d_factor = diag_dominance_factor(pl.band_pc)
+            d_host = float(d_factor)  # host read: the variant depends on it
+        variant = resolve_variant(opts.variant, d_host)
+        sp.annotate(variant=variant, d_factor=d_host)
         with span("factor.split"):
             bt = band_to_block_tridiag(pl.band_pc, max(pl.k, 1), opts.p)
         pc = build_preconditioner(

@@ -165,6 +165,7 @@ def _reduce_level_pallas(d, e, f, boost_eps, interpret):
         out_specs=pl.BlockSpec((1, k, k), lambda i: (i, 0, 0)),
         out_shape=sd((m2, k, k), d.dtype),
         interpret=interpret,
+        name="sap_bcr_inv_odd",
         compiler_params=_PARALLEL,
     )(d)
 
@@ -182,6 +183,7 @@ def _reduce_level_pallas(d, e, f, boost_eps, interpret):
         out_specs=_specs(k, k, *([a_cur] * 5)),
         out_shape=[sd((m2, k, k), d.dtype)] * 5,
         interpret=interpret,
+        name="sap_bcr_reduce",
         compiler_params=_PARALLEL,
     )(d, e, e, e, f, f, f, a_odd, a_odd)
     level = BCRLevel(lo=lo, hi=hi, a_odd=a_odd, e_odd=e[1::2], f_odd=f[1::2])
@@ -267,6 +269,7 @@ def bcr_solve_pallas(
             out_specs=pl.BlockSpec((1, k, r), cur),
             out_shape=sd((m2, k, r), b.dtype),
             interpret=interpret,
+            name="sap_bcr_rhs_reduce",
             compiler_params=_PARALLEL,
         )(lv.lo, lv.hi, b, b, b)
 
@@ -281,6 +284,7 @@ def bcr_solve_pallas(
             out_specs=pl.BlockSpec((2, k, r), cur),
             out_shape=sd((2 * m2, k, r), x.dtype),
             interpret=interpret,
+            name="sap_bcr_backsub",
             compiler_params=_PARALLEL,
         )(lv.a_odd, lv.e_odd, lv.f_odd, b_odd, x, x)
     return x[:factors.m, :k0, :r0]

@@ -85,6 +85,7 @@ def btf_pallas(
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((k, k), jnp.float32)],
         interpret=interpret,
+        name="sap_btf",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),

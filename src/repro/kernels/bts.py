@@ -89,6 +89,7 @@ def bts_pallas(
         out_shape=jax.ShapeDtypeStruct(b.shape, b.dtype),
         scratch_shapes=[pltpu.VMEM((k, r), jnp.float32)],
         interpret=interpret,
+        name="sap_bts_forward",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
@@ -104,6 +105,7 @@ def bts_pallas(
         out_shape=jax.ShapeDtypeStruct(b.shape, b.dtype),
         scratch_shapes=[pltpu.VMEM((k, r), jnp.float32)],
         interpret=interpret,
+        name="sap_bts_backward",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),

@@ -165,6 +165,7 @@ def fused_factor_spike_pallas(
             pltpu.VMEM((k, k), jnp.float32),  # c_v
         ],
         interpret=interpret,
+        name="sap_fused_factor_spike",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),

@@ -49,7 +49,7 @@ import jax
 
 from ..launch.hlo_stats import analyze_hlo
 from ..launch.roofline import HardwareSpec, backend_spec
-from .trace import span
+from .trace import mark, span
 
 __all__ = [
     "COMPILES",
@@ -173,7 +173,9 @@ def install_compile_listener() -> bool:
 
     Returns True when the ``jax.monitoring`` listener is active.  JAX
     offers registration but no removal, so this is once-per-process --
-    the callback only bumps two counters under a lock.
+    the callback bumps two counters under a lock and, while the profiler
+    runs, leaves a zero-length ``sap.backend_compile`` marker on the
+    compiling thread, inside the span that caused the compile.
     """
     with _LISTENER_LOCK:
         if COMPILES.listener_installed:
@@ -184,6 +186,7 @@ def install_compile_listener() -> bool:
             def _listener(event: str, duration: float, **kw: Any) -> None:
                 if event == _COMPILE_EVENT:
                     COMPILES._on_event(duration)
+                    mark("backend_compile")
 
             monitoring.register_event_duration_secs_listener(_listener)
             COMPILES.listener_installed = True

@@ -15,10 +15,18 @@ factorization, or Krylov iteration?  Usage:
 
 Design constraints:
 
-- **Zero overhead when disabled.**  The module-level ``span()`` helper
-  returns a shared no-op singleton when no tracer is active (one global
-  read + one ``is None`` check); instrumented code never pays for
-  timestamps, dict churn, or lock traffic unless a tracer is installed.
+- **On the profiler's clock.**  Outside JAX tracing every span also opens
+  ``jax.profiler.TraceAnnotation("sap.<name>")``, tracer or not, so a
+  ``jax.profiler.trace(dir)`` capture shows the solver's own spans next
+  to the device's operations.  Attributes stay out of the annotation's
+  name.  Without an installed tracer such a span is annotation-only:
+  falsy, recorded nowhere in memory, and its ``sync`` never blocks, so it
+  does not change the schedule it measures.
+- **Near-zero overhead when off.**  With no enabled tracer and the
+  profiler not running, the module-level ``span()`` helper returns a
+  shared no-op singleton after a global read and the profiler's own
+  enabled check; instrumented code never pays for timestamps, dict
+  churn, or lock traffic.
 - **Trace-safe.**  Instrumented functions also run under ``jax.jit`` /
   ``vmap`` (e.g. the batched factor stages).  Host-side timing of traced
   code is meaningless and attribute values would be tracers, so ``span()``
@@ -27,8 +35,9 @@ Design constraints:
   drain thread traces concurrently with client threads); finished roots
   are collected under a lock.
 - **Honest device timing.**  JAX dispatch is async even on CPU; a span
-  that launches device work should call ``sp.sync(result)`` so the span
-  exit blocks on the result before taking the end timestamp.
+  that launches device work should call ``sp.sync(result)`` so that,
+  under an installed ``Tracer(device_sync=True)``, the span exit blocks
+  on the result before taking the end timestamp.
 """
 
 from __future__ import annotations
@@ -46,10 +55,15 @@ __all__ = [
     "Span",
     "Tracer",
     "get_tracer",
+    "mark",
     "record",
     "span",
     "use_tracer",
 ]
+
+
+# Name prefix of the solver's annotations in the profiler's trace.
+PREFIX = "sap."
 
 
 def _under_jax_trace() -> bool:
@@ -108,6 +122,24 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class _AnnotationSpan(_NullSpan):
+    """A span that only annotates the profiler's trace: falsy like the
+    no-op span, and its ``sync`` never blocks."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str):
+        self._ann = jax.profiler.TraceAnnotation(PREFIX + name)
+
+    def __enter__(self) -> "_AnnotationSpan":
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc: Any) -> bool:
+        self._ann.__exit__(*exc)
+        return False
+
+
 class Span:
     """A timed, attributed region.  Created via ``Tracer.span`` / ``span()``."""
 
@@ -145,12 +177,8 @@ class Span:
         tracer = self._tracer
         self.tid = threading.get_ident()
         tracer._stack().append(self)
-        if tracer.annotate_xla:
-            try:
-                self._ann = jax.profiler.TraceAnnotation(self.name)
-                self._ann.__enter__()
-            except Exception:  # pragma: no cover - profiler backend unavailable
-                self._ann = None
+        self._ann = jax.profiler.TraceAnnotation(PREFIX + self.name)
+        self._ann.__enter__()
         self.t0 = tracer.clock()
         return self
 
@@ -163,12 +191,8 @@ class Span:
                 jax.block_until_ready(pending)
         finally:
             self.t1 = self._tracer.clock()
-            if self._ann is not None:
-                try:
-                    self._ann.__exit__(*exc)
-                except Exception:  # pragma: no cover
-                    pass
-                self._ann = None
+            self._ann.__exit__(*exc)
+            self._ann = None
             self._tracer._finish(self)
         return False
 
@@ -187,29 +211,26 @@ class Tracer:
     Parameters
     ----------
     enabled:
-        When False every ``span()`` returns the no-op singleton; an
-        instrumented code path costs one attribute read per span site.
+        When False the tracer records nothing, and ``span()`` behaves as
+        with no tracer installed.
     device_sync:
         When True (default), spans that registered a value via
         ``sp.sync(x)`` call ``jax.block_until_ready`` before taking the
         end timestamp, so durations reflect device completion rather than
         async dispatch.
-    annotate_xla:
-        When True, each host span also opens a
-        ``jax.profiler.TraceAnnotation`` of the same name, so spans line
-        up with XLA events inside ``jax.profiler.trace`` captures.
+
+    Each span it records also annotates the profiler's trace as
+    ``sap.<name>``, like every span outside JAX tracing.
     """
 
     def __init__(
         self,
         enabled: bool = True,
         device_sync: bool = True,
-        annotate_xla: bool = False,
         clock: Callable[[], float] = time.perf_counter,
     ):
         self.enabled = enabled
         self.device_sync = device_sync
-        self.annotate_xla = annotate_xla
         self.clock = clock
         self._epoch = clock()
         self._lock = threading.Lock()
@@ -415,11 +436,25 @@ def get_tracer() -> Optional[Tracer]:
 
 
 def span(name: str, **attrs: Any):
-    """Open a span on the active tracer; no-op (and allocation-free) without one."""
+    """Open a span: on the active tracer when one is enabled, else an
+    annotation-only ``sap.<name>`` span while the profiler runs, else the
+    no-op singleton.  Under JAX tracing it is always the no-op singleton."""
     t = _ACTIVE
-    if t is None:
+    if t is not None and t.enabled:
+        return t.span(name, **attrs)
+    # cheapest test first: the profiler's enabled flag, then the trace state
+    if not jax.profiler.TraceAnnotation.is_enabled() or _under_jax_trace():
         return NULL_SPAN
-    return t.span(name, **attrs)
+    return _AnnotationSpan(name)
+
+
+def mark(name: str) -> None:
+    """A zero-length ``sap.<name>`` event in the profiler's trace, on the
+    calling thread; nothing when the profiler is off."""
+    ann = jax.profiler.TraceAnnotation
+    if ann.is_enabled():
+        with ann(PREFIX + name):
+            pass
 
 
 def record(name: str, t0: float, t1: float, **attrs: Any) -> None:

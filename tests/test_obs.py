@@ -7,6 +7,7 @@ import threading
 import time
 from pathlib import Path
 
+import jax
 import numpy as np
 import pytest
 
@@ -61,6 +62,160 @@ def test_disabled_tracer_returns_null_span():
 def test_module_span_without_active_tracer_is_null():
     assert get_tracer() is None
     assert span("anything") is NULL_SPAN
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation`` with the profiler on:
+    records each annotation's enter and exit with its thread."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    @staticmethod
+    def is_enabled():
+        return True
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, threading.get_ident()))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, threading.get_ident()))
+        return False
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    _Annotations.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotations)
+    return _Annotations.log
+
+
+class _NoBlock:
+    """A pytree leaf that fails if anything blocks on it."""
+
+    def block_until_ready(self):
+        raise AssertionError("the span blocked")
+
+
+def test_span_without_tracer_annotates_and_never_blocks(annotations):
+    assert get_tracer() is None
+    value = _NoBlock()
+    with span("factor", n=4) as sp:
+        assert not sp  # falsy: `if sp:` attribute work stays off
+        assert sp.sync(value) is value
+        sp.annotate(variant="C")
+    # the name carries no attributes
+    assert [(k, n) for k, n, _ in annotations] == [("enter", "sap.factor"),
+                                                   ("exit", "sap.factor")]
+
+
+def test_span_without_tracer_or_profiler_is_null():
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert span("factor") is NULL_SPAN
+
+
+def test_span_under_jit_is_null_with_profiler_on(annotations):
+    import jax.numpy as jnp
+
+    seen = []
+
+    @jax.jit
+    def f(x):
+        with span("inside") as sp:
+            seen.append(sp)
+        return x + 1.0
+
+    assert float(f(jnp.float32(1.0))) == 2.0
+    assert seen == [NULL_SPAN]
+    assert not [n for _, n, _ in annotations if n == "sap.inside"]
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_tracer_span_records_and_annotates(annotations, enabled):
+    tr = Tracer(enabled=enabled)
+    with use_tracer(tr):
+        with span("factor") as sp:
+            with span("factor.split"):
+                pass
+    assert bool(sp) is enabled
+    assert [(k, n) for k, n, _ in annotations] == [
+        ("enter", "sap.factor"), ("enter", "sap.factor.split"),
+        ("exit", "sap.factor.split"), ("exit", "sap.factor")]
+    if enabled:
+        (root,) = tr.roots()
+        assert [c.name for c in root.children] == ["factor.split"]
+    else:
+        assert tr.roots() == []
+
+
+def test_compile_marker_lands_inside_the_open_span(annotations):
+    import jax.numpy as jnp
+
+    from repro.obs.cost import COMPILES, install_compile_listener
+
+    assert install_compile_listener()
+    count0 = COMPILES.totals()[0]
+    with span("factor.reduced"):
+        jax.jit(lambda x: x * 3.0 - 1.0)(jnp.arange(5.0)).block_until_ready()
+    assert COMPILES.totals()[0] > count0
+    names = [n for _, n, _ in annotations]
+    assert names[0] == "sap.factor.reduced" and names[-1] == "sap.factor.reduced"
+    inner = names[1:-1]
+    assert inner and set(inner) == {"sap.backend_compile"}
+    assert inner == ["sap.backend_compile"] * len(inner)
+    assert len({tid for _, _, tid in annotations}) == 1  # on the compiling thread
+
+
+@pytest.mark.parametrize("kernel,names", [
+    ("bts", ["sap_bts_forward", "sap_bts_backward"]),
+    ("btf", ["sap_btf"]),
+    ("fused", ["sap_fused_factor_spike"]),
+    ("bcr_factor", ["sap_bcr_inv_odd", "sap_bcr_reduce"]),
+    ("bcr_solve", ["sap_bcr_rhs_reduce", "sap_bcr_backsub"]),
+])
+def test_solver_kernels_have_stable_names(kernel, names):
+    import jax.numpy as jnp
+    from jax.extend import core as jcore
+
+    from repro.kernels.bcr import bcr_factor_pallas, bcr_solve_pallas
+    from repro.kernels.btf import btf_pallas
+    from repro.kernels.bts import bts_pallas
+    from repro.kernels.fused_spike import fused_factor_spike_pallas
+
+    p, m, k, r = 2, 4, 8, 2
+    blk = jnp.zeros((p, m, k, k), jnp.float32)
+    corner = jnp.zeros((p, k, k), jnp.float32)
+    chain = jnp.broadcast_to(jnp.eye(k, dtype=jnp.float32), (m, k, k))
+    calls = {
+        "bts": lambda: bts_pallas(blk, blk, blk, jnp.zeros((p, m, k, r)), interpret=True),
+        "btf": lambda: btf_pallas(blk + jnp.eye(k), blk, blk, interpret=True),
+        "fused": lambda: fused_factor_spike_pallas(
+            blk + jnp.eye(k), blk, blk, corner, corner, interpret=True),
+        "bcr_factor": lambda: bcr_factor_pallas(chain, 0 * chain, 0 * chain,
+                                                interpret=True),
+        "bcr_solve": lambda: bcr_solve_pallas(
+            bcr_factor_pallas(chain, 0 * chain, 0 * chain, interpret=True),
+            jnp.zeros((m, k, r)), interpret=True),
+    }
+    jaxpr = jax.make_jaxpr(calls[kernel])()
+
+    def called(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["name"]
+            for v in eqn.params.values():
+                if isinstance(v, jcore.ClosedJaxpr):
+                    yield from called(v.jaxpr)
+                elif isinstance(v, jcore.Jaxpr):
+                    yield from called(v)
+
+    found = list(called(jaxpr.jaxpr))
+    # the factor of the solve's chain is traced too; keep the solve's own
+    assert set(names) <= set(found)
+    assert all(n.startswith("sap_") for n in found)
 
 
 def test_use_tracer_nests_and_restores():
@@ -289,20 +444,23 @@ def test_disabled_overhead_under_two_percent():
         one_pass()
     warm_solve_s = (time.perf_counter() - t0) / 5
 
-    # per-site cost of an instrumented span with tracing disabled
-    with use_tracer(Tracer(enabled=False)):
-        n = 10_000
-        t0 = time.perf_counter()
-        for _ in range(n):
-            with span("engine.solve_prepared", bucket="256x4", batch=1):
-                pass
-        per_site_s = (time.perf_counter() - t0) / n
-    # the hot path crosses a handful of span sites per solve; even 10x
-    # that stays far under the 2% budget
-    assert per_site_s * 10 < 0.02 * warm_solve_s, (
-        f"null-span overhead {per_site_s * 1e9:.0f} ns/site vs warm solve "
-        f"{warm_solve_s * 1e6:.0f} us"
-    )
+    # per-site cost of an instrumented span with tracing off: a disabled
+    # tracer installed, and no tracer at all; the profiler is off in both
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    for tracer in (Tracer(enabled=False), None):
+        with use_tracer(tracer):
+            n = 10_000
+            t0 = time.perf_counter()
+            for _ in range(n):
+                with span("engine.solve_prepared", bucket="256x4", batch=1):
+                    pass
+            per_site_s = (time.perf_counter() - t0) / n
+        # the hot path crosses a handful of span sites per solve; even 10x
+        # that stays far under the 2% budget
+        assert per_site_s * 10 < 0.02 * warm_solve_s, (
+            f"null-span overhead {per_site_s * 1e9:.0f} ns/site vs warm solve "
+            f"{warm_solve_s * 1e6:.0f} us (tracer {tracer})"
+        )
 
 
 # ---------------------------------------------------------------------------
