@@ -58,15 +58,61 @@ def mm(a: jax.Array, b: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+# Largest block the pivot loop inverts whole: 128 lanes, so the block and
+# its inverse are 2 x 16 vregs at most and stay in registers.
+GJ_LEAF = 128
+
+
 def gj_inverse(a: jax.Array, boost_eps: float = DEFAULT_BOOST) -> jax.Array:
     """Inverse of a (K, K) block via Gauss-Jordan with pivot boosting.
 
-    Rows of ``a`` that are *exactly* zero (structurally decoupled slots,
-    e.g. identity padding) are never boosted: their pivot is taken as 1,
-    so the returned inverse acts as the identity on those slots instead of
-    a ``1/thr``-sized perturbation.  Elimination never fills a zero row
-    (its multiplier column entry is zero), so the test at step ``t`` sees
-    the original structure of row ``t``.
+    A pivot smaller than ``thr = boost_eps * max|a|`` is boosted to
+    ``+-thr``, so the result is the exact inverse of ``a + dA`` with a
+    diagonal ``dA`` (paper Sec. 2.2).  Rows of ``a`` that are *exactly*
+    zero (structurally decoupled slots, e.g. identity padding) are never
+    boosted: their pivot is taken as 1, so the returned inverse acts as the
+    identity on those slots instead of a ``1/thr``-sized perturbation.
+
+    Up to ``GJ_LEAF`` the pivot loop of :func:`_gj_leaf` inverts the block
+    whole.  Above it the block is inverted by the 2 x 2 block (Schur
+    complement) recursion of :func:`_gj_blocked`: the same pivots in the
+    same order and the same ``thr``, with the O(K^3) work in matmuls (the
+    MXU in a kernel) and the pivot loops only on lane-wide diagonal
+    leaves.  Only the order of the roundings differs from one loop over
+    all K pivots.
+    """
+    if a.shape[-1] <= GJ_LEAF:
+        return _gj_leaf(a, _boost_threshold(a, boost_eps))
+    return _gj_inverse_blocked(a, boost_eps)
+
+
+def _boost_threshold(a: jax.Array, boost_eps: float) -> jax.Array:
+    """``boost_eps * max|a|`` as a (1, 1) array: the whole block's pivot
+    boosting threshold."""
+    scale = jnp.max(jnp.max(jnp.abs(a), axis=1, keepdims=True), axis=0,
+                    keepdims=True)
+    return boost_eps * jnp.maximum(scale, jnp.asarray(1e-30, a.dtype))
+
+
+@partial(jax.jit, static_argnames=("boost_eps",))
+def _gj_inverse_blocked(a: jax.Array, boost_eps: float) -> jax.Array:
+    """The blocked inverse as one compiled unit: a caller outside ``jit``
+    (the reduced systems' ``vmap``) then compiles it once per shape rather
+    than dispatching its slices, products and two pivot loops one by one."""
+    return _gj_blocked(a, _boost_threshold(a, boost_eps), None)
+
+
+def _gj_leaf(
+    a: jax.Array, thr: jax.Array, outside: jax.Array | None = None
+) -> jax.Array:
+    """The boosted Gauss-Jordan pivot loop over all K pivots of ``a``.
+
+    ``outside`` (K, 1), when given, is max |entry| of each row in the
+    columns of the enclosing block that lie right of ``a``: a row with a
+    nonzero there is not structurally zero, so it is boosted like any
+    other, never exempt.  Elimination never fills a zero row (its
+    multiplier column entry is zero), so the test at step ``t`` sees the
+    original structure of row ``t``.
 
     The loop body does no dynamic indexing: row and column ``t`` are
     selected with ``iota == t`` masks (a masked sum adds only zeros to the
@@ -78,9 +124,6 @@ def gj_inverse(a: jax.Array, boost_eps: float = DEFAULT_BOOST) -> jax.Array:
     """
     k = a.shape[-1]
     dtype = a.dtype
-    scale = jnp.max(jnp.max(jnp.abs(a), axis=1, keepdims=True), axis=0,
-                    keepdims=True)
-    thr = boost_eps * jnp.maximum(scale, jnp.asarray(1e-30, dtype))  # (1, 1)
     rows = jax.lax.broadcasted_iota(jnp.int32, (k, k), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (k, k), 1)
     inv = (rows == cols).astype(dtype)
@@ -95,7 +138,12 @@ def gj_inverse(a: jax.Array, boost_eps: float = DEFAULT_BOOST) -> jax.Array:
         col = jnp.sum(jnp.where(csel, a, zero), axis=1, keepdims=True)
         at_t = csel[:1]  # (1, K): column t of a row vector
         piv = jnp.sum(jnp.where(at_t, a_row, zero), axis=1, keepdims=True)
-        struct_zero = jnp.max(jnp.abs(a_row), axis=1, keepdims=True) == 0
+        live = jnp.max(jnp.abs(a_row), axis=1, keepdims=True)
+        if outside is not None:  # row t of the (K, 1) column ``outside``
+            live = jnp.maximum(live, jnp.sum(
+                jnp.where(rows[:, :1] == t, outside, zero), axis=0,
+                keepdims=True))
+        struct_zero = live == 0
         piv = jnp.where(
             jnp.abs(piv) < thr, jnp.where(piv >= 0, thr, -thr), piv
         )
@@ -110,6 +158,50 @@ def gj_inverse(a: jax.Array, boost_eps: float = DEFAULT_BOOST) -> jax.Array:
 
     _, inv = jax.lax.fori_loop(0, k, step, (a, inv))
     return inv
+
+
+def _gj_blocked(
+    a: jax.Array, thr: jax.Array, outside: jax.Array | None
+) -> jax.Array:
+    """Boosted inverse of ``a`` by the 2 x 2 block (Schur) recursion.
+
+    With ``a = [[A11, A12], [A21, A22]]`` split at ``h``, a multiple of
+    128 (``h = 128 * ceil(K / 256)``: 200 -> 128 + 72, 400 -> 256 + 144):
+
+        inv11 = inv(A11)            pivots 0 .. h-1
+        S     = A22 - A21 inv11 A12
+        invS  = inv(S)              pivots h .. K-1
+        inv(a) = [[inv11 + X invS Y, -X invS], [-invS Y, invS]]
+
+    with ``X = inv11 A12`` and ``Y = A21 inv11``.  These are the pivots of
+    one Gauss-Jordan sweep over ``a``, so boosting them with the whole
+    block's ``thr`` inverts the same ``a + dA``.  A row of A11 is
+    structurally zero only if its A12 part is zero too, so A12's row
+    maxima join ``outside`` for the A11 leaf; the rows of S own their
+    whole current row.  Slices and concatenations are static and at
+    multiples of 128, which Mosaic lowers.
+    """
+    k = a.shape[-1]
+    if k <= GJ_LEAF:
+        return _gj_leaf(a, thr, outside)
+    h = GJ_LEAF * -(-k // (2 * GJ_LEAF))
+    a11, a12 = a[:h, :h], a[:h, h:]
+    a21, a22 = a[h:, :h], a[h:, h:]
+    out1 = jnp.max(jnp.abs(a12), axis=1, keepdims=True)
+    out2 = None
+    if outside is not None:
+        out1 = jnp.maximum(outside[:h], out1)
+        out2 = outside[h:]
+    inv11 = _gj_blocked(a11, thr, out1)
+    x = mm(inv11, a12)
+    y = mm(a21, inv11)
+    inv_s = _gj_blocked(a22 - mm(a21, x), thr, out2)
+    b12 = -mm(x, inv_s)
+    b21 = -mm(inv_s, y)
+    b11 = inv11 - mm(b12, y)
+    return jnp.concatenate(
+        [jnp.concatenate([b11, b12], axis=1),
+         jnp.concatenate([b21, inv_s], axis=1)], axis=0)
 
 
 def gj_solve(a: jax.Array, b: jax.Array, boost_eps: float = DEFAULT_BOOST) -> jax.Array:

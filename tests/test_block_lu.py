@@ -11,6 +11,9 @@ from repro.core.banded import (
     random_banded,
 )
 from repro.core.block_lu import (
+    GJ_LEAF,
+    _boost_threshold,
+    _gj_leaf,
     btf_ref,
     btf_ul_ref,
     bts_ref,
@@ -31,6 +34,48 @@ def test_gj_inverse_pivot_boosting_no_nan():
     a = jnp.zeros((6, 6)).at[0, 0].set(1.0)
     inv = gj_inverse(a, boost_eps=1e-8)
     assert bool(jnp.all(jnp.isfinite(inv)))
+
+
+def _dominant_block(k, seed):
+    """d = 1 dominant (K, K) block: U(-1, 1) off the diagonal and
+    |a_ii| = sum_j |a_ij|, the law of the paper's dense banded test."""
+    rng = np.random.default_rng(seed)
+    a = rng.uniform(-1.0, 1.0, size=(k, k))
+    np.fill_diagonal(a, 0.0)
+    np.fill_diagonal(a, np.abs(a).sum(axis=1))
+    return a
+
+
+def _sequential(a):
+    """One pivot loop over all K pivots: what gj_inverse runs at K <= 128."""
+    return jax.jit(_gj_leaf)(a, _boost_threshold(a, 1e-10))
+
+
+@pytest.mark.parametrize("k", [129, 136, 200, 256, 400])
+def test_gj_inverse_blocked_matches_sequential(k):
+    """Above GJ_LEAF the Schur-complement recursion inverts the same block
+    as one sweep of K pivots, to the sweep's own accuracy."""
+    a64 = _dominant_block(k, seed=k)
+    a = jnp.asarray(a64, jnp.float32)
+    inv = np.asarray(gj_inverse(a), np.float64)
+    seq = np.asarray(_sequential(a), np.float64)
+    exact = np.linalg.inv(np.asarray(a, np.float64))
+    eye = np.eye(k)
+    err_seq = np.linalg.norm(seq @ a64 - eye)
+    assert np.linalg.norm(inv @ a64 - eye) <= 2 * err_seq
+    scale = np.abs(exact).max()
+    np.testing.assert_allclose(inv, exact, rtol=0, atol=1e-5 * scale)
+    np.testing.assert_allclose(inv, seq, rtol=0, atol=1e-5 * scale)
+
+
+@pytest.mark.parametrize("k", [8, 100, GJ_LEAF])
+def test_gj_inverse_leaf_is_the_sequential_loop(k):
+    """Up to GJ_LEAF nothing changed: bit for bit the one pivot loop."""
+    a = jnp.asarray(_dominant_block(k, seed=k), jnp.float32)
+    np.testing.assert_array_equal(
+        np.asarray(gj_inverse(a)), np.asarray(_sequential(a)))
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(gj_inverse)(a)), np.asarray(_sequential(a)))
 
 
 @pytest.mark.parametrize("n,k,p,r", [(60, 4, 3, 1), (96, 8, 2, 5), (70, 5, 7, 2)])

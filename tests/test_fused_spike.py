@@ -35,9 +35,12 @@ from repro.kernels import ops
 
 
 def _chain(rng, p, m, k, dtype=jnp.float32):
-    """Well-conditioned block-tridiag chain + off-partition couplings."""
+    """Well-conditioned block-tridiag chain + off-partition couplings.
+
+    The diagonal shift outgrows the spectral radius of a normal K x K
+    block (about sqrt(K)) once K is past a few dozen."""
     r = lambda *s: jnp.asarray(rng.normal(size=s), dtype)
-    d = r(p, m, k, k) + 4 * jnp.eye(k, dtype=dtype)
+    d = r(p, m, k, k) + max(4.0, k / 8) * jnp.eye(k, dtype=dtype)
     e = r(p, m, k, k) * 0.3
     f = r(p, m, k, k) * 0.3
     b_cpl = r(p - 1, k, k) * 0.3
@@ -82,7 +85,8 @@ def _assert_corner_parity(fs, d, e, f, b_cpl, c_cpl):
 
 @pytest.mark.parametrize(
     "p,m,k",
-    [(2, 1, 3), (2, 4, 8), (3, 5, 3), (4, 3, 4), (5, 2, 2), (3, 7, 5)],
+    [(2, 1, 3), (2, 4, 8), (3, 5, 3), (4, 3, 4), (5, 2, 2), (3, 7, 5),
+     (2, 3, 136)],
 )
 def test_fused_ref_matches_sequence(p, m, k):
     """Non-pow2 grids included; M = 1 exercises the init-only path."""
@@ -99,7 +103,9 @@ def test_fused_ref_matches_sequence(p, m, k):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("p,m,k", [(2, 3, 4), (3, 5, 3), (2, 4, 8), (4, 1, 4)])
+@pytest.mark.parametrize(
+    "p,m,k", [(2, 3, 4), (3, 5, 3), (2, 4, 8), (4, 1, 4), (2, 3, 136)]
+)
 def test_fused_kernel_interpret_bit_parity(p, m, k):
     rng = np.random.default_rng(7)
     d, e, f, b_cpl, c_cpl = _chain(rng, p, m, k)

@@ -163,26 +163,64 @@ def test_solver_matches_unpadded_through_k_rounding(variant):
 # ---------------------------------------------------------------------------
 
 
-def test_gj_inverse_identity_on_structurally_zero_rows():
-    """A block whose trailing rows/cols are identity-padded inverts to
+def _live_block(k, seed):
+    """d = 1 dominant (K, K) block, U(-1, 1) off the diagonal."""
+    a = np.random.default_rng(seed).uniform(-1.0, 1.0, size=(k, k))
+    np.fill_diagonal(a, 0.0)
+    np.fill_diagonal(a, np.abs(a).sum(axis=1))
+    return a
+
+
+# K = 5 is one pivot loop; K = 200 is inverted by the blocked recursion,
+# split at h = 128, so the padded slots sit on both sides of the split
+@pytest.mark.parametrize("k,pad,h", [(5, (3, 4), 3),
+                                     (200, (0, 61, 127, 128, 170, 199), 128)])
+def test_gj_inverse_identity_on_structurally_zero_rows(k, pad, h):
+    """A block whose rows/cols ``pad`` are identity-padded inverts to
     the inverse of the live block plus identity slots -- no 1/boost_eps
-    garbage in the padded rows."""
-    rng = np.random.default_rng(3)
-    a_live = rng.normal(size=(3, 3))
-    blk = np.zeros((5, 5))
-    blk[:3, :3] = a_live
-    # structurally zero rows 3, 4 (identity-slot semantics)
+    garbage in the padded rows.  A row that is zero left of ``h`` but not
+    right of it is boosted like any other, and singular blocks stay
+    finite."""
+    pad = list(pad)
+    live = np.setdiff1d(np.arange(k), pad)
+    # a normal block of 194 rows would need pivoting: take the dominant law
+    a_live = (_live_block(live.size, seed=3) if k > 128 else
+              np.random.default_rng(3).normal(size=(live.size, live.size)))
+    blk = np.zeros((k, k))
+    blk[np.ix_(live, live)] = a_live
+    # structurally zero rows (identity-slot semantics)
     inv = np.asarray(gj_inverse(jnp.asarray(blk, FDTYPE), boost_eps=1e-10))
     np.testing.assert_allclose(
-        inv[:3, :3], np.linalg.inv(a_live), rtol=1e-5, atol=1e-6
+        inv[np.ix_(live, live)], np.linalg.inv(a_live), rtol=1e-5, atol=1e-6
     )
-    np.testing.assert_array_equal(inv[3:, :3], 0.0)
-    np.testing.assert_array_equal(inv[:3, 3:], 0.0)
-    np.testing.assert_array_equal(inv[3:, 3:], np.eye(2))
+    np.testing.assert_array_equal(inv[np.ix_(pad, live)], 0.0)
+    np.testing.assert_array_equal(inv[np.ix_(live, pad)], 0.0)
+    np.testing.assert_array_equal(inv[np.ix_(pad, pad)], np.eye(len(pad)))
     # numerically small but structurally nonzero pivots still boost
-    tiny = jnp.asarray(np.diag([1.0, 1e-30]), FDTYPE)
-    inv_t = np.asarray(gj_inverse(tiny, boost_eps=1e-10))
-    assert np.isfinite(inv_t).all() and inv_t[1, 1] < 1e12
+    diag = np.ones(k)
+    diag[-1] = 1e-30
+    inv_t = np.asarray(gj_inverse(jnp.asarray(np.diag(diag), FDTYPE),
+                                  boost_eps=1e-10))
+    assert np.isfinite(inv_t).all() and inv_t[-1, -1] < 1e12
+    # row 1 is zero left of h (its pivot too) but not right of it: the
+    # whole row is not zero, so its pivot is boosted to thr, not taken as 1
+    a = _live_block(k, seed=4)
+    a[1, :h] = 0.0
+    eps = 1e-3
+    thr = eps * np.abs(a).max()
+    bump = np.zeros((k, k))
+    bump[1, 1] = 1.0
+    inv_b = np.asarray(gj_inverse(jnp.asarray(a, FDTYPE), boost_eps=eps))
+    boosted = np.linalg.inv(a + thr * bump)
+    scale = np.abs(boosted).max()
+    np.testing.assert_allclose(inv_b, boosted, rtol=0, atol=1e-5 * scale)
+    assert np.abs(inv_b - np.linalg.inv(a + bump)).max() > 0.1 * scale
+    # singular blocks: every row equal, or one row repeated across h
+    dup = _live_block(k, seed=5)
+    dup[-1] = dup[1]
+    for sing in (np.ones((k, k)), dup):
+        inv_s = gj_inverse(jnp.asarray(sing, FDTYPE), boost_eps=1e-10)
+        assert bool(jnp.all(jnp.isfinite(inv_s)))
 
 
 # ---------------------------------------------------------------------------
