@@ -640,6 +640,27 @@ def index_factorization(bfac: BatchedSaPFactorization, i: int) -> SaPFactorizati
     return jax.tree_util.tree_map(lambda x: x[i], bfac.fac)
 
 
+@jax.jit
+def _unstack(fac: SaPFactorization) -> List[SaPFactorization]:
+    s = jax.tree_util.tree_leaves(fac)[0].shape[0]
+    return [jax.tree_util.tree_map(lambda x: x[i], fac) for i in range(s)]
+
+
+def unstack_factorizations(bfac: BatchedSaPFactorization) -> List[SaPFactorization]:
+    """Every system of the batch as a standalone factorization, in one
+    device call (:func:`index_factorization` for each ``i`` dispatches
+    one slice per leaf and system)."""
+    return _unstack(bfac.fac)
+
+
+@jax.jit
+def stack_trees(trees: Sequence) -> object:
+    """Stack same-structure pytrees (or arrays) along a new leading axis in
+    one device call: one compiled program per count and shape, where an
+    eager ``jnp.stack`` dispatches once per member."""
+    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
+
+
 def stack_factorizations(
     facs: Sequence[SaPFactorization], orig_ns: Optional[Sequence[int]] = None
 ) -> BatchedSaPFactorization:
@@ -658,7 +679,7 @@ def stack_factorizations(
             "cannot stack factorizations from different buckets/variants: "
             f"{len(treedefs)} distinct pytree structures"
         )
-    stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *facs)
+    stacked = stack_trees(facs)
     ns = tuple(orig_ns) if orig_ns is not None else (facs[0].n,) * len(facs)
     return BatchedSaPFactorization(fac=stacked, s=len(facs), orig_ns=ns)
 

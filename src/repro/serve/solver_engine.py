@@ -30,6 +30,26 @@ solves run *outside* the locks -- host-side bookkeeping of incoming
 requests overlaps in-flight device work.
 
 Cache-hit and throughput counters live on :attr:`SolverEngine.stats`.
+
+**Time stepping: the caller keys its Jacobians.**  A simulation code
+knows when it re-evaluates a Jacobian; hashing the band to find that out
+would copy every band to the host on every step.  So a caller that sets
+``SolveRequest.fingerprint`` names the matrix itself, one key per
+Jacobian version (e.g. ``f"{system}.{version}"``).  Such a request is
+never hashed, and with a fixed variant (not ``"auto"``, whose first
+batch scans dominance on the host) the engine reads only the band's
+shape on the host: a device-resident band stays on the device from
+submit to solve, and its right-hand side is stacked into the batch on
+the device.  The key is trusted: a changed band under a key already
+cached is solved with the cached factorization, so a new Jacobian must
+carry a new key.  Requests without a key are keyed by
+:func:`matrix_fingerprint`.
+
+Each batch runs under the spans ``engine.prep`` (keying, dominance,
+padding, stacking the right-hand sides), ``engine.factor`` (the misses'
+batched factor, synced), ``engine.stack`` (slicing fresh factorizations
+into the cache and stacking the batch's) and ``engine.solve`` (the
+batched Krylov solve and its results), inside ``engine.solve_prepared``.
 """
 
 from __future__ import annotations
@@ -92,7 +112,9 @@ class SolveRequest:
     rid: int
     band: np.ndarray | jnp.ndarray  # (N, 2K+1) band storage
     b: np.ndarray | jnp.ndarray  # (N,) right-hand side
-    fingerprint: Optional[str] = None  # filled by submit() if absent
+    # the caller's key of the matrix (time stepping: one per Jacobian
+    # version, never hashed); filled by submit() with the band's hash if absent
+    fingerprint: Optional[str] = None
     result: Optional["SolveOutcome"] = None
 
     @property
@@ -199,6 +221,12 @@ class SolverEngine:
             "evictions": 0,
             "misconverged": 0,
             "escalations": 0,
+            # Krylov iterations summed over answers, and the slowest lane
+            # of each batch summed over batches: the vmapped while_loop
+            # runs every lane until the slowest converges, so the gap
+            # between the per-batch mean and max is work done for nothing
+            "krylov_iters_total": 0.0,
+            "krylov_lane_max_total": 0.0,
             # monotonic wall-clock split of solve_prepared, maintained
             # whether or not a tracer is active: factor_seconds_total is
             # the device-synced batch-factoring of cache misses,
@@ -361,79 +389,88 @@ class SolverEngine:
         t0 = time.perf_counter()
         t_factor = 0.0
         nb, kb, _ = bucket
-        for r in batch:
-            if r.fingerprint is None:
-                r.fingerprint = matrix_fingerprint(r.band)
+        with span("engine.prep", batch=len(batch)):
+            for r in batch:
+                if r.fingerprint is None:
+                    r.fingerprint = matrix_fingerprint(r.band)
 
-        internal = opts is None
-        with self._lock:
-            eff = self.opts if internal else opts
-        # "auto" resolves per batch from the worst (minimum) host-side
-        # dominance estimate, *before* the cache lookup so the resolved
-        # variant is part of the cache key.  The internal path stays
-        # sticky: the first resolution pins self.opts so every later
-        # step stacks structurally identical factorizations.
-        if eff.variant == "auto":
-            d_min = min(band_dominance(r.band) for r in batch)
-            eff = dataclasses.replace(
-                eff, variant=resolve_variant("auto", d_min)
+            internal = opts is None
+            with self._lock:
+                eff = self.opts if internal else opts
+            # "auto" resolves per batch from the worst (minimum) host-side
+            # dominance estimate, *before* the cache lookup so the resolved
+            # variant is part of the cache key.  The internal path stays
+            # sticky: the first resolution pins self.opts so every later
+            # step stacks structurally identical factorizations.
+            if eff.variant == "auto":
+                d_min = min(band_dominance(r.band) for r in batch)
+                eff = dataclasses.replace(
+                    eff, variant=resolve_variant("auto", d_min)
+                )
+                if internal:
+                    with self._lock:
+                        if self.opts.variant == "auto":
+                            self.opts = eff
+                        eff = self.opts
+            sig = _opts_sig(eff)
+
+            # A batch may repeat a fingerprint (same Jacobian, many RHS
+            # requests): each distinct matrix is factored once, duplicates
+            # count as hits.  ``step_facs`` pins this step's factorizations
+            # locally -- the LRU may evict mid-step (cache_size < distinct
+            # matrices in one batch) without pulling them out from under
+            # the solve.
+            step_facs: dict = {}
+            miss_fps: List[str] = []
+            miss_reqs: List[SolveRequest] = []
+            is_hit: List[bool] = []
+            for r in batch:
+                cached = self._cache_get((r.fingerprint, bucket, sig))
+                if cached is not None:
+                    step_facs[r.fingerprint] = cached
+                    is_hit.append(True)
+                elif r.fingerprint in miss_fps:
+                    is_hit.append(True)
+                else:
+                    is_hit.append(False)
+                    miss_fps.append(r.fingerprint)
+                    miss_reqs.append(r)
+            if miss_reqs:
+                bpl = _plan_for_bucket([r.band for r in miss_reqs], bucket, eff)
+            bmat = batched.stack_trees(
+                [batched.pad_rhs_to(r.b, nb) for r in batch]
             )
-            if internal:
-                with self._lock:
-                    if self.opts.variant == "auto":
-                        self.opts = eff
-                    eff = self.opts
-        sig = _opts_sig(eff)
 
-        # 1) factor the cache misses in ONE vmapped pass.  A batch may
-        #    repeat a fingerprint (same Jacobian, many RHS requests): each
-        #    distinct matrix is factored once, duplicates count as hits.
-        #    ``step_facs`` pins this step's factorizations locally -- the
-        #    LRU may evict mid-step (cache_size < distinct matrices in
-        #    one batch) without pulling them out from under the solve.
-        step_facs: dict = {}
-        miss_fps: List[str] = []
-        miss_reqs: List[SolveRequest] = []
-        is_hit: List[bool] = []
-        for r in batch:
-            cached = self._cache_get((r.fingerprint, bucket, sig))
-            if cached is not None:
-                step_facs[r.fingerprint] = cached
-                is_hit.append(True)
-            elif r.fingerprint in miss_fps:
-                is_hit.append(True)
-            else:
-                is_hit.append(False)
-                miss_fps.append(r.fingerprint)
-                miss_reqs.append(r)
+        # 1) factor the cache misses in ONE vmapped pass
         if miss_reqs:
             tf0 = time.perf_counter()
-            bpl = _plan_for_bucket([r.band for r in miss_reqs], bucket, eff)
-            bfac = batched.batch_factor(bpl)
-            # block here so the factor-vs-solve wall-clock split is honest
-            # (dispatch is async; unsynced, factoring would bill to solve)
-            jax.block_until_ready(bfac.fac.pc)
+            with span("engine.factor", systems=len(miss_reqs)):
+                bfac = batched.batch_factor(bpl)
+                # block here so the factor-vs-solve wall-clock split is
+                # honest (dispatch is async; unsynced, factoring would bill
+                # to solve)
+                jax.block_until_ready(bfac.fac.pc)
             t_factor = time.perf_counter() - tf0
-            for j, fp in enumerate(miss_fps):
-                fac = batched.index_factorization(bfac, j)
-                step_facs[fp] = fac
-                self._cache_put((fp, bucket, sig), fac)
-            self._bump("factored_systems", len(miss_reqs))
+        self._bump("factored_systems", len(miss_reqs))
         self._bump("cache_hits", sum(is_hit))
         self._bump("cache_misses", len(is_hit) - sum(is_hit))
 
         # 2) one batched solve over cached + fresh factorizations
-        facs = [step_facs[r.fingerprint] for r in batch]
-        orig_ns = [np.shape(r.band)[0] for r in batch]
-        bfac = batched.stack_factorizations(facs, orig_ns)
-        bmat = jnp.stack(
-            [batched.pad_rhs_to(jnp.asarray(r.b), nb) for r in batch]
-        )
-        res = bfac.solve_batch(bmat, record_history=eff.record_history)
-        xs = batched.unpad_solution(res.x, orig_ns)
-        iters = np.asarray(res.iterations)
-        rnorm = np.asarray(res.resnorm)
-        conv = np.asarray(res.converged)
+        with span("engine.stack", batch=len(batch)):
+            if miss_reqs:
+                for fp, fac in zip(miss_fps, batched.unstack_factorizations(bfac)):
+                    step_facs[fp] = fac
+                    self._cache_put((fp, bucket, sig), fac)
+            orig_ns = [np.shape(r.band)[0] for r in batch]
+            bfac = batched.stack_factorizations(
+                [step_facs[r.fingerprint] for r in batch], orig_ns
+            )
+        with span("engine.solve", batch=len(batch)):
+            res = bfac.solve_batch(bmat, record_history=eff.record_history)
+            xs = batched.unpad_solution(res.x, orig_ns)
+            iters = np.asarray(res.iterations)
+            rnorm = np.asarray(res.resnorm)
+            conv = np.asarray(res.converged)
         hists = np.asarray(res.history) if res.history is not None else None
         if res.true_resnorm is not None:
             tres = np.asarray(res.true_resnorm)
@@ -464,6 +501,8 @@ class SolverEngine:
         with self._lock:
             self.stats["solved"] += len(batch)
             self.stats["steps"] += 1
+            self.stats["krylov_iters_total"] += float(iters.sum())
+            self.stats["krylov_lane_max_total"] += float(iters.max())
             self.stats["factor_seconds_total"] += t_factor
             self.stats["solve_seconds_total"] += dt - t_factor
             self.stats["solve_seconds"] += dt

@@ -271,3 +271,118 @@ def test_submit_precomputed_fingerprint_respected():
     assert req.fingerprint == "custom-fp"
     (done,) = eng.step()
     assert done.rid == 99 and done.result.converged
+
+
+class _NumpySpy:
+    """numpy, recording each conversion of a watched array to numpy."""
+
+    CONVERT = ("asarray", "array", "ascontiguousarray")
+
+    def __init__(self, watched):
+        self.watched, self.seen = watched, []
+
+    def __getattr__(self, name):
+        fn = getattr(np, name)
+        if name not in self.CONVERT:
+            return fn
+
+        def spy(a, *args, **kw):
+            if any(a is w for w in self.watched):
+                self.seen.append(name)
+            return fn(a, *args, **kw)
+
+        return spy
+
+
+def test_keyed_request_never_hashes_nor_reads_its_band(monkeypatch):
+    """The time-stepping contract: a request whose caller names its
+    Jacobian is never hashed and its device band never becomes numpy."""
+    from repro.serve import solver_engine
+
+    band = jnp.asarray(_mat(200, 4, seed=5))
+    _, b = _rhs_for(np.asarray(band), seed=0)
+    spy = _NumpySpy([band])
+    hashed = []
+    real_fp = solver_engine.matrix_fingerprint
+    monkeypatch.setattr(solver_engine, "np", spy)
+    monkeypatch.setattr(batched, "np", spy)
+    monkeypatch.setattr(solver_engine, "matrix_fingerprint",
+                        lambda a: hashed.append(a) or real_fp(a))
+    eng = _engine()
+    for step in range(2):  # a miss, then a hit
+        eng.submit(SolveRequest(rid=step, band=band, b=jnp.asarray(b),
+                                fingerprint="jacobian.0"))
+        (done,) = eng.step()
+        assert done.result.converged and done.result.cache_hit == (step > 0)
+    assert hashed == [] and spy.seen == []
+    # the spies see the default path: an unkeyed request is hashed on the host
+    eng.submit_system(band, b)
+    eng.step()
+    assert len(hashed) == 1 and spy.seen
+
+
+# A small seeded fleet under the time-stepping schedule: 8 systems, 2 of
+# them refreshed a step (systems 2(s mod 4), 2(s mod 4) + 1), 6 steps after
+# the step that factors every system's first Jacobian.
+FLEET_N, FLEET_K, SYSTEMS, REFRESH, STEPS = 256, 4, 8, 2, 6
+COUNTERS = ("cache_hits", "cache_misses", "factored_systems", "escalations",
+            "krylov_iters_total", "krylov_lane_max_total", "steps")
+
+
+@pytest.fixture(scope="module")
+def fleet():
+    eng = SolverEngine(
+        SaPOptions(p=4, variant="C", tol=1e-6, maxiter=300, solver="bicgstab2"),
+        max_batch=SYSTEMS, cache_size=32,
+    )
+    version = [0] * SYSTEMS
+    rng = np.random.default_rng(0)
+    steps = []
+    for s in range(-1, STEPS):
+        if s >= 0:
+            first = REFRESH * (s % (SYSTEMS // REFRESH))
+            for i in range(first, first + REFRESH):
+                version[i] += 1
+        bands = [_mat(FLEET_N, FLEET_K, seed=1000 * i + version[i], d=1.2)
+                 for i in range(SYSTEMS)]
+        bs = [rng.normal(size=FLEET_N).astype(np.float32) for _ in range(SYSTEMS)]
+        before = {c: eng.stats[c] for c in COUNTERS}
+        for i in range(SYSTEMS):
+            eng.submit(SolveRequest(rid=i, band=jnp.asarray(bands[i]),
+                                    b=jnp.asarray(bs[i]),
+                                    fingerprint=f"{i}.{version[i]}"))
+        done = sorted(eng.run_until_drained(), key=lambda r: r.rid)
+        steps.append({"bands": bands, "bs": bs, "done": done,
+                      "delta": {c: eng.stats[c] - before[c] for c in COUNTERS}})
+    return steps
+
+
+def test_time_stepping_fleet_matches_dense_solves(fleet):
+    from repro.core.banded import band_to_dense
+
+    for st in fleet:
+        for band, b, r in zip(st["bands"], st["bs"], st["done"]):
+            dense = np.asarray(band_to_dense(jnp.asarray(band)), np.float64)
+            x = np.linalg.solve(dense, b.astype(np.float64))
+            # The engine accepts an answer whose f32 true residual is within
+            # its guard, 10 * tol = 1e-5 (it escalates otherwise), and the
+            # forward error is at most cond(A) times the relative residual.
+            err = np.linalg.norm(r.result.x - x) / np.linalg.norm(x)
+            assert err <= np.linalg.cond(dense) * 1e-5
+            assert r.result.converged and not r.result.escalated
+
+
+def test_time_stepping_fleet_counters(fleet):
+    first, *steps = fleet
+    assert first["delta"]["factored_systems"] == SYSTEMS
+    assert len(steps) == STEPS
+    for st in steps:
+        d, its = st["delta"], [r.result.iterations for r in st["done"]]
+        assert d["steps"] == 1  # one batch a step
+        assert d["factored_systems"] == d["cache_misses"] == REFRESH
+        assert d["cache_hits"] == SYSTEMS - REFRESH
+        assert [r.result.cache_hit for r in st["done"]].count(False) == REFRESH
+        assert d["escalations"] == 0
+        assert d["krylov_lane_max_total"] == max(its)
+        assert d["krylov_iters_total"] / SYSTEMS == pytest.approx(np.mean(its))
+        assert d["krylov_lane_max_total"] >= d["krylov_iters_total"] / SYSTEMS
