@@ -79,6 +79,21 @@ def _dot(a, b):
 # ---------------------------------------------------------------------------
 
 
+def bicgstab2_applies(iterations, x0_given: bool = False) -> int:
+    """Preconditioner applies BiCGStab(2) runs for an exit at ``iterations``.
+
+    One apply starts the loop (``r0 = M^-1 b``; with ``x0`` a second one
+    gives ``||M^-1 b||``), each whole sweep takes four, and a lane that
+    leaves a sweep at quarter 0.25 / 0.5 has run one / three of them.  The
+    matvecs come to the same count: one per loop apply, one for ``x0``'s
+    residual, and the exit's ``true_resnorm`` in place of ``r0``'s.  Under
+    ``vmap`` the loop runs every lane as long as the slowest, so the count
+    at the slowest lane's ``iterations`` is what the batched loop runs.
+    """
+    sweeps, quarter = divmod(float(iterations), 1.0)
+    return (2 if x0_given else 1) + 4 * int(sweeps) + {0.0: 0, 0.25: 1, 0.5: 3}[quarter]
+
+
 def _bicgstab2_impl(
     matvec: MatVec,
     b: jax.Array,
@@ -90,81 +105,91 @@ def _bicgstab2_impl(
 ) -> KrylovResult:
     """BiCGStab(2) with left preconditioning (unjitted body).
 
-    One outer "iteration" = two matvec+precond in the BiCG part plus two in
-    the MR part, counted as 4 quarter-exits to mirror the paper's tables.
+    One outer "iteration" (sweep, Sleijpen & Fokkema Alg. 3.1, l = 2) =
+    two ``M^-1 A`` applies in the BiCG part plus two in the MR part, with
+    exit points after the 1st (quarter 0.25), 3rd (0.5) and 4th (1.0)
+    apply, counted in quarters to mirror the paper's tables (Sec. 4.1.1).
+    Each ``while_loop`` trip runs exactly one apply, and the sweep's phase
+    (0..3) rides in the loop state, so a lane leaves at the first exit it
+    meets and no apply runs whose result would be thrown away -- under
+    ``vmap`` the loop ends only when every lane has left it.
 
     ``record_history`` is a static flag: when True a fixed-size ``(maxiter,)``
     NaN-initialized residual array rides through the while_loop state and is
-    returned on ``KrylovResult.history``; when False the loop state is
-    byte-identical to before the flag existed (no recompilation of cached
-    history-free executables).
+    returned on ``KrylovResult.history``; when False the loop carries no
+    history at all.
     """
     dtype = b.dtype
     op = lambda v: precond(matvec(v)).astype(dtype)
 
-    x = jnp.zeros_like(b) if x0 is None else x0
-    r0 = precond(b - matvec(x)).astype(dtype)
-    bnorm = jnp.linalg.norm(precond(b).astype(dtype))
+    if x0 is None:
+        # b - A 0 = b: no matvec of the zero vector, and ||M^-1 b|| is ||r0||
+        x = jnp.zeros_like(b)
+        r0 = precond(b).astype(dtype)
+        bnorm = jnp.linalg.norm(r0)
+    else:
+        x = x0
+        r0 = precond(b - matvec(x)).astype(dtype)
+        bnorm = jnp.linalg.norm(precond(b).astype(dtype))
     bnorm = jnp.where(bnorm > 0, bnorm, 1.0)
     rtilde = r0
     eps = jnp.asarray(1e-300 if dtype == jnp.float64 else 1e-30, dtype)
 
     def cond(state):
-        (x, r, u, rho, omega, alpha, it, done) = state[:8]
+        (it, done) = state[9:11]
         return (~done) & (it < maxiter)
 
-    def _select(c, a, b):
-        return jax.tree.map(lambda p, q: jnp.where(c, p, q), a, b)
+    def _exit(r0, it, quarter):
+        q = jnp.linalg.norm(r0) <= tol * bnorm
+        return jnp.where(q, it + quarter, it), q
 
-    def body(state):
-        """One BiCGStab(2) sweep (Sleijpen & Fokkema Alg. 3.1, l = 2).
-
-        The algorithm has intermediate exit points (the paper counts them
-        as quarter iterations, Sec. 4.1.1).  If an early exit triggers we
-        keep the snapshot at that point -- continuing the sweep with a
-        (near-)zero residual would divide by degenerate inner products.
-        """
-        (x, r0, u0, rho0, omega, alpha, it, done) = state[:8]
-        # `it` at sweep entry is always whole (quarter-exits end the loop),
-        # so it doubles as the 0-based history index for this sweep.
-        sweep_idx = it.astype(jnp.int32)
-        rho0 = -omega * rho0
-
-        # ---- BiCG part, j = 0 -------------------------------------------
+    def opening(r0, u0, rho0, omega, alpha):
+        # a sweep opens (j = 0): new rho and beta, search direction u0
+        rho_prev = -omega * rho0
         rho1 = _dot(r0, rtilde)
-        beta = jnp.where(jnp.abs(rho0) > eps, alpha * rho1 / rho0, 0.0)
-        rho0 = rho1
-        u0 = r0 - beta * u0
-        u1 = op(u0)
+        beta = jnp.where(jnp.abs(rho_prev) > eps, alpha * rho1 / rho_prev, 0.0)
+        return r0 - beta * u0, rho1
+
+    # One branch per phase, run after that phase's apply ``w``.  `it` stays
+    # whole inside a sweep and steps by the quarter the lane leaves at.
+    def bicg0(w, x, r0, r1, u0, u1, u2, rho0, omega, alpha, it):
+        # BiCG part, j = 0: u1 = M^-1 A u0; quarter-exit 1
+        u1 = w
         gamma = _dot(u1, rtilde)
         alpha = jnp.where(jnp.abs(gamma) > eps, rho0 / gamma, 0.0)
         r0 = r0 - alpha * u1
-        r1 = op(r0)
         x = x + alpha * u0
-        q1 = jnp.linalg.norm(r0) <= tol * bnorm  # quarter-exit 1
-        snap1 = (x, r0, u0, rho0, omega, alpha, it + 0.25, q1)
+        it, q = _exit(r0, it, 0.25)
+        return (x, r0, r1, u0, u1, u2, rho0, omega, alpha, it, q)
 
-        # ---- BiCG part, j = 1 -------------------------------------------
+    def bicg1(w, x, r0, r1, u0, u1, u2, rho0, omega, alpha, it):
+        # BiCG part, j = 1: r1 = M^-1 A r0, new directions
+        r1 = w
         rho1 = _dot(r1, rtilde)
         beta = jnp.where(jnp.abs(rho0) > eps, alpha * rho1 / rho0, 0.0)
         rho0 = rho1
         u0 = r0 - beta * u0
         u1 = r1 - beta * u1
-        u2 = op(u1)
+        return (x, r0, r1, u0, u1, u2, rho0, omega, alpha, it, jnp.asarray(False))
+
+    def bicg2(w, x, r0, r1, u0, u1, u2, rho0, omega, alpha, it):
+        # u2 = M^-1 A u1; quarter-exit 2
+        u2 = w
         gamma = _dot(u2, rtilde)
         alpha = jnp.where(jnp.abs(gamma) > eps, rho0 / gamma, 0.0)
         r0 = r0 - alpha * u1
         r1 = r1 - alpha * u2
-        r2 = op(r1)
         x = x + alpha * u0
-        q2 = jnp.linalg.norm(r0) <= tol * bnorm  # quarter-exit 2
-        snap2 = (x, r0, u0, rho0, omega, alpha, it + 0.5, q2)
+        it, q = _exit(r0, it, 0.5)
+        return (x, r0, r1, u0, u1, u2, rho0, omega, alpha, it, q)
 
-        # ---- MR part (modified Gram-Schmidt on r1, r2) -------------------
+    def mr(w, x, r0, r1, u0, u1, u2, rho0, omega, alpha, it):
+        # r2 = M^-1 A r1; MR part (modified Gram-Schmidt on r1, r2).
         # Degeneracy guard: when the preconditioner is (near-)exact,
         # r2 - tau12 r1 is rounding noise; using it poisons x while the
         # recurrence residual stays small.  Detect via the relative norm of
         # the orthogonalized direction and fall back to the l=1 step.
+        r2 = w
         sigma1 = jnp.maximum(_dot(r1, r1), eps)
         gp1 = _dot(r0, r1) / sigma1
         tau12 = _dot(r2, r1) / sigma1
@@ -178,37 +203,66 @@ def _bicgstab2_impl(
             degenerate, 0.0, _dot(r0, r2o) / jnp.maximum(sigma2, eps)
         )
         g2 = gp2
-        omega_new = jnp.where(degenerate, gp1, g2)
+        omega = jnp.where(degenerate, gp1, g2)
         g1 = gp1 - tau12 * g2
         gpp1 = g2  # gamma''_1 = gamma_2 (l = 2)
 
         x = x + g1 * r0 + gpp1 * r1
         r0 = r0 - gp1 * r1 - gp2 * r2o
         u0 = u0 - g1 * u1 - g2 * u2
+        it = it + 1.0
+        q = jnp.linalg.norm(r0) <= tol * bnorm
+        return (x, r0, r1, u0, u1, u2, rho0, omega, alpha, it, q)
 
-        q4 = jnp.linalg.norm(r0) <= tol * bnorm
-        full = (x, r0, u0, rho0, omega_new, alpha, it + 1.0, q4)
-        new = _select(q1, snap1, _select(q2, snap2, full))
+    def body(state):
+        (x, r0, r1, u0, u1, u2, rho0, omega, alpha, it, done, phase) = state[:12]
+        # `it` is whole until the lane leaves, so it doubles as the 0-based
+        # history index of the sweep
+        sweep_idx = it.astype(jnp.int32)
+        u0, rho0 = jax.lax.cond(
+            phase == 0,
+            opening,
+            lambda r0, u0, rho0, omega, alpha: (u0, rho0),
+            r0, u0, rho0, omega, alpha,
+        )
+        # the one apply of this trip, on the vector the phase needs
+        v = jax.lax.select_n(phase, u0, r0, u1, r1)
+        w = op(v)
+        new = jax.lax.switch(
+            phase, (bicg0, bicg1, bicg2, mr),
+            w, x, r0, r1, u0, u1, u2, rho0, omega, alpha, it,
+        )
+        new = new + ((phase + 1) % 4,)
         if record_history:
-            hist = state[8].at[sweep_idx].set(jnp.linalg.norm(new[1]) / bnorm)
-            return new + (hist,)
+            # one entry per sweep: where the lane leaves it, or at its end
+            write = new[10] | (phase == 3)
+            hist = state[12]
+            val = jnp.where(
+                write, jnp.linalg.norm(new[1]) / bnorm, hist[sweep_idx]
+            )
+            return new + (hist.at[sweep_idx].set(val),)
         return new
 
-    u = jnp.zeros_like(b)
+    zero = jnp.zeros_like(b)
     state = (
         x,
         r0,
-        u,
+        zero,  # r1
+        zero,  # u0
+        zero,  # u1
+        zero,  # u2
         jnp.asarray(1.0, dtype),  # rho0
         jnp.asarray(1.0, dtype),  # omega
         jnp.asarray(0.0, dtype),  # alpha
         jnp.asarray(0.0, dtype),  # iterations
         jnp.linalg.norm(r0) <= tol * bnorm,
+        jnp.asarray(0, jnp.int32),  # phase: applies done in this sweep
     )
     if record_history:
         state = state + (jnp.full((maxiter,), jnp.nan, dtype),)
     out = jax.lax.while_loop(cond, body, state)
-    (x, r, _, _, _, _, it, done) = out[:8]
+    (x, r) = out[:2]
+    (it, done) = out[9:11]
     rnorm = jnp.linalg.norm(r)
     return KrylovResult(
         x=x,
@@ -216,7 +270,7 @@ def _bicgstab2_impl(
         resnorm=rnorm / bnorm,
         converged=done,
         true_resnorm=_true_resnorm(matvec, b, x),
-        history=out[8] if record_history else None,
+        history=out[12] if record_history else None,
     )
 
 

@@ -67,7 +67,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import batched
-from repro.core.sap import SaPOptions, resolve_variant
+from repro.core.krylov import bicgstab2_applies
+from repro.core.sap import SaPOptions, resolve_solver, resolve_variant
 from repro.kernels.ops import default_impl
 from repro.obs import cost as obs_cost
 from repro.obs.trace import span
@@ -227,6 +228,9 @@ class SolverEngine:
             # between the per-batch mean and max is work done for nothing
             "krylov_iters_total": 0.0,
             "krylov_lane_max_total": 0.0,
+            # BiCGStab(2) batches: the preconditioner applies the vmapped
+            # loop runs, bicgstab2_applies at the slowest lane, summed
+            "krylov_applies_total": 0,
             # monotonic wall-clock split of solve_prepared, maintained
             # whether or not a tracer is active: factor_seconds_total is
             # the device-synced batch-factoring of cache misses,
@@ -503,6 +507,8 @@ class SolverEngine:
             self.stats["steps"] += 1
             self.stats["krylov_iters_total"] += float(iters.sum())
             self.stats["krylov_lane_max_total"] += float(iters.max())
+            if resolve_solver(eff.solver, eff.use_cg) == "bicgstab2":
+                self.stats["krylov_applies_total"] += bicgstab2_applies(iters.max())
             self.stats["factor_seconds_total"] += t_factor
             self.stats["solve_seconds_total"] += dt - t_factor
             self.stats["solve_seconds"] += dt

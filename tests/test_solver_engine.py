@@ -8,6 +8,7 @@ import pytest
 
 from repro.core import SaPOptions, batched
 from repro.core.banded import band_matvec, random_banded
+from repro.core.krylov import bicgstab2_applies
 from repro.serve import SolveRequest, SolverEngine, matrix_fingerprint
 
 
@@ -326,7 +327,8 @@ def test_keyed_request_never_hashes_nor_reads_its_band(monkeypatch):
 # the step that factors every system's first Jacobian.
 FLEET_N, FLEET_K, SYSTEMS, REFRESH, STEPS = 256, 4, 8, 2, 6
 COUNTERS = ("cache_hits", "cache_misses", "factored_systems", "escalations",
-            "krylov_iters_total", "krylov_lane_max_total", "steps")
+            "krylov_iters_total", "krylov_lane_max_total", "krylov_applies_total",
+            "steps")
 
 
 @pytest.fixture(scope="module")
@@ -386,3 +388,12 @@ def test_time_stepping_fleet_counters(fleet):
         assert d["krylov_lane_max_total"] == max(its)
         assert d["krylov_iters_total"] / SYSTEMS == pytest.approx(np.mean(its))
         assert d["krylov_lane_max_total"] >= d["krylov_iters_total"] / SYSTEMS
+
+
+def test_time_stepping_fleet_applies_counter(fleet):
+    """krylov_applies_total grows by bicgstab2_applies at the batch's
+    slowest lane: the applies the vmapped loop runs."""
+    for st in fleet:
+        its = [r.result.iterations for r in st["done"]]
+        assert st["delta"]["krylov_applies_total"] == bicgstab2_applies(max(its))
+        assert st["delta"]["krylov_applies_total"] >= 2
