@@ -1,6 +1,5 @@
 """Kernel microbenchmarks: jnp reference path vs Pallas interpret path
-(correctness-weighted; true kernel perf numbers require TPU hardware) and
-LM step benches for the reduced configs."""
+(correctness-weighted; true kernel perf numbers require TPU hardware)."""
 
 from __future__ import annotations
 
@@ -69,62 +68,6 @@ def bench_bcr_chain(report: Report):
                    f"speedup={us_ss / us_sb:.2f}x;maxdiff={err:.1e}")
 
 
-def bench_scan_kernels(report: Report):
-    rng = np.random.default_rng(1)
-    b, h, t, dd = 2, 8, 512, 64
-    r = jnp.asarray(rng.normal(size=(b, h, t, dd)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(b, h, t, dd)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(b, h, t, dd)), jnp.float32)
-    lw = -jnp.exp(jnp.asarray(rng.normal(size=(b, h, t, dd)), jnp.float32) * 0.5)
-    u = jnp.asarray(rng.normal(size=(h, dd)), jnp.float32)
-    s0 = jnp.zeros((b, h, dd, dd), jnp.float32)
-    for chunk in (32, 64, 128):
-        us = timeit(lambda: ops.wkv6(r, k, v, lw, u, s0, chunk=chunk,
-                                     impl="jnp")[0])
-        report.add(f"kernel/wkv6/jnp/T{t}/chunk{chunk}", us, "")
-    # sequential reference for contrast (the chunked speedup story)
-    us_seq = timeit(lambda: ref.wkv6_ref(r, k, v, lw, u, s0)[0], iters=1)
-    report.add(f"kernel/wkv6/sequential/T{t}", us_seq, "")
-
-    n, pd = 64, 64
-    x = jnp.asarray(rng.normal(size=(b, h, t, pd)), jnp.float32)
-    bm = jnp.asarray(rng.normal(size=(b, h, t, n)), jnp.float32)
-    cm = jnp.asarray(rng.normal(size=(b, h, t, n)), jnp.float32)
-    la = -jnp.exp(jnp.asarray(rng.normal(size=(b, h, t)), jnp.float32) * 0.5)
-    ss = jnp.zeros((b, h, n, pd), jnp.float32)
-    for chunk in (32, 64, 128):
-        us = timeit(lambda: ops.ssd(x, bm, cm, la, ss, chunk=chunk,
-                                    impl="jnp")[0])
-        report.add(f"kernel/ssd/jnp/T{t}/chunk{chunk}", us, "")
-
-
-def bench_lm_steps(report: Report):
-    from repro.configs import get_config
-    from repro.models import get_family
-
-    rng = jax.random.PRNGKey(0)
-    for arch in ("stablelm-1.6b", "rwkv6-1.6b", "zamba2-2.7b",
-                 "deepseek-moe-16b"):
-        cfg = get_config(arch, reduced=True)
-        fam = get_family(cfg)
-        params = fam.init(cfg, rng)
-        batch = {"tokens": jax.random.randint(rng, (4, 128), 0, cfg.vocab)}
-
-        def loss_fn(p):
-            return fam.loss(cfg, p, batch)[0]
-
-        step = jax.jit(jax.value_and_grad(loss_fn))
-        us = timeit(lambda: step(params)[0])
-        report.add(f"lm/train_step_reduced/{arch}", us, "b4xs128")
-        cache = fam.init_cache(cfg, 4, 128)
-        dstep = jax.jit(lambda p, c, t: fam.decode_step(cfg, p, c, t))
-        tok = jnp.zeros((4, 1), jnp.int32)
-        us = timeit(lambda: dstep(params, cache, tok)[0])
-        report.add(f"lm/decode_step_reduced/{arch}", us, "b4")
-
-
 def run(report: Report):
     bench_btf(report)
     bench_bcr_chain(report)
-    bench_scan_kernels(report)
-    bench_lm_steps(report)

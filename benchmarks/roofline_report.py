@@ -2,7 +2,7 @@
 
 Two sources, one renderer:
 
-  * ``results/dryrun/*.json`` (from benchmarks/dryrun_sweep.py) -- the
+  * ``results/dryrun/*.json`` (``python -m repro.launch.dryrun --out``) -- the
     markdown tables for EXPERIMENTS.md (``--table roofline`` /
     ``--table dryrun``).  The results directory is a flag now
     (``--results-dir``), not a hard-coded path, so sweeps written
@@ -153,7 +153,7 @@ def main(argv=None):
         results_dir = Path(args.results_dir)
         if not results_dir.exists():
             text = (f"no results under {results_dir} -- run "
-                    "benchmarks/dryrun_sweep.py first (or pass "
+                    "python -m repro.launch.dryrun --out first (or pass "
                     "--results-dir)\n")
         else:
             rows = load(results_dir, args.mesh)

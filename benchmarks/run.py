@@ -1,8 +1,8 @@
 """Benchmark driver: one module per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV.  Sizes are CPU-scaled; the
-full-scale (arch x shape x mesh) numbers come from the dry-run/roofline
-pipeline (see benchmarks/dryrun_sweep.py + benchmarks/roofline_report.py).
+full-scale (shape x mesh) numbers come from the dry-run/roofline pipeline
+(see repro.launch.dryrun + benchmarks/roofline_report.py).
 """
 
 from __future__ import annotations

@@ -1,49 +1,8 @@
-"""Architecture registry: ``--arch <id>`` resolves here.
+"""Solver workload configurations.
 
-Each module defines ``full()`` (the published configuration, verbatim from
-the assignment) and ``reduced()`` (a same-family miniature for CPU smoke
-tests).  ``sap_solver`` is the paper's own workload (banded linear solve)
-and has its own config type.
+``sap_solver`` is the paper's own workload (banded linear solve): the
+``SolverConfig`` presets (``full``, ``reduced``, ``exact``, ``service``,
+``fleet``) and the dry-run ``SOLVER_SHAPES``.
 """
 
-from __future__ import annotations
-
-from repro.models.api import ModelConfig
-
-from . import (
-    deepseek_moe_16b,
-    minitron_8b,
-    mixtral_8x22b,
-    phi3_mini_3_8b,
-    phi3_vision_4_2b,
-    rwkv6_1_6b,
-    sap_solver,
-    stablelm_1_6b,
-    starcoder2_15b,
-    whisper_medium,
-    zamba2_2_7b,
-)
-
-ARCHS = {
-    "rwkv6-1.6b": rwkv6_1_6b,
-    "mixtral-8x22b": mixtral_8x22b,
-    "deepseek-moe-16b": deepseek_moe_16b,
-    "phi3-mini-3.8b": phi3_mini_3_8b,
-    "stablelm-1.6b": stablelm_1_6b,
-    "minitron-8b": minitron_8b,
-    "starcoder2-15b": starcoder2_15b,
-    "zamba2-2.7b": zamba2_2_7b,
-    "phi-3-vision-4.2b": phi3_vision_4_2b,
-    "whisper-medium": whisper_medium,
-}
-
-SOLVER_ARCHS = {"sap-solver": sap_solver}
-
-
-def get_config(name: str, reduced: bool = False) -> ModelConfig:
-    mod = ARCHS[name]
-    return mod.reduced() if reduced else mod.full()
-
-
-def arch_names():
-    return list(ARCHS)
+from . import sap_solver  # noqa: F401

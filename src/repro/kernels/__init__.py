@@ -3,25 +3,20 @@
 The paper hand-optimizes exactly these stages on the GPU (Sec. 3.1); here
 they are TPU-native Pallas kernels:
 
-  * ``btf``        -- block-tridiagonal factorization (the paper's banded
-                      LU "window sliding", re-blocked for the MXU)
-  * ``bts``        -- forward/backward block solves (preconditioner apply
-                      + spike computation)
-  * ``wkv_chunk``  -- chunked RWKV6 recurrence (SaP applied along the
-                      sequence axis of a block-bidiagonal system)
-  * ``ssd_chunk``  -- chunked Mamba-2 SSD recurrence (same, scalar decay)
-  * ``flash_attn`` -- causal/windowed GQA flash attention (beyond-paper,
-                      motivated by the roofline memory term)
+  * ``btf``         -- block-tridiagonal factorization (the paper's banded
+                       LU "window sliding", re-blocked for the MXU)
+  * ``bts``         -- forward/backward block solves (preconditioner apply
+                       + spike computation)
+  * ``fused_spike`` -- factor + spike-corner extraction in one pass
+  * ``bcr``         -- block cyclic reduction (log-depth SaP-E interface
+                       chain factor/solve)
 
 ``ops`` holds the jit'd dispatch wrappers, ``ref`` the pure-jnp oracles.
 """
 
 from . import ops, ref  # noqa: F401
-from .flash_attn import flash_attention_pallas  # noqa: F401
 from .ops import (  # noqa: F401
     block_tridiag_factor,
     block_tridiag_solve,
     default_impl,
-    ssd,
-    wkv6,
 )

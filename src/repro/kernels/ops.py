@@ -1,5 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels with implementation dispatch.
 
+The kernels are ``btf`` (block-tridiagonal factor), ``bts`` (block
+solves), ``fused_spike`` (factor + spike corners in one pass) and ``bcr``
+(block cyclic reduction for the SaP-E interface chain).
+
 ``impl`` selects the execution path:
   * "jnp"       -- pure-jnp reference (default on CPU; identical math)
   * "interpret" -- Pallas kernel executed in interpret mode (CPU-validated)
@@ -18,10 +22,8 @@ so ``boost_eps`` only ever perturbs *numerically* small pivots.
 from __future__ import annotations
 
 import os
-from typing import Tuple
 
 import jax
-import jax.numpy as jnp
 
 from repro.core.block_lu import (
     DEFAULT_BOOST,
@@ -37,8 +39,6 @@ from .bcr import bcr_factor_pallas, bcr_solve_pallas
 from .btf import btf_pallas
 from .bts import bts_pallas
 from .fused_spike import fused_factor_spike_pallas
-from .ssd_chunk import ssd_pallas
-from .wkv_chunk import wkv6_pallas
 
 
 IMPLS = ("jnp", "interpret", "pallas")
@@ -318,55 +318,3 @@ def bcr_flops(m: int, k: int) -> float:
     across the log2(M) levels, each paying one inverse (2 K^3) and four
     update products (2 K^3 each)."""
     return float(m) * 10.0 * k**3
-
-
-# ---------------------------------------------------------------------------
-# Sequence-mixing recurrences (flattened over batch x heads)
-# ---------------------------------------------------------------------------
-
-
-def wkv6(
-    r: jax.Array,  # (B, H, T, D)
-    k: jax.Array,
-    v: jax.Array,
-    logw: jax.Array,
-    u: jax.Array,  # (H, D)
-    state: jax.Array,  # (B, H, D, D)
-    chunk: int = 64,
-    impl: str | None = None,
-) -> Tuple[jax.Array, jax.Array]:
-    """Chunked WKV6 recurrence; returns (output, final state)."""
-    impl = impl or default_impl()
-    if impl == "jnp":
-        return ref.wkv6_chunked_ref(r, k, v, logw, u, state, chunk)
-    bsz, h, t, d = r.shape
-    flat = lambda x: x.reshape(bsz * h, *x.shape[2:])
-    u_full = jnp.broadcast_to(u, (bsz,) + u.shape).reshape(bsz * h, d)
-    o, s_out = wkv6_pallas(
-        flat(r), flat(k), flat(v), flat(logw), u_full,
-        state.reshape(bsz * h, d, d), chunk=chunk, interpret=_interpret(impl),
-    )
-    return o.reshape(bsz, h, t, d), s_out.reshape(bsz, h, d, d)
-
-
-def ssd(
-    x: jax.Array,  # (B, H, T, P)
-    b: jax.Array,  # (B, H, T, N)
-    c: jax.Array,  # (B, H, T, N)
-    loga: jax.Array,  # (B, H, T)
-    state: jax.Array,  # (B, H, N, P)
-    chunk: int = 64,
-    impl: str | None = None,
-) -> Tuple[jax.Array, jax.Array]:
-    """Chunked SSD (state-space dual) scan; returns (output, final state)."""
-    impl = impl or default_impl()
-    if impl == "jnp":
-        return ref.ssd_chunked_ref(x, b, c, loga, state, chunk)
-    bsz, h, t, p = x.shape
-    n = b.shape[-1]
-    flat = lambda a: a.reshape(bsz * h, *a.shape[2:])
-    y, s_out = ssd_pallas(
-        flat(x), flat(b), flat(c), flat(loga),
-        state.reshape(bsz * h, n, p), chunk=chunk, interpret=_interpret(impl),
-    )
-    return y.reshape(bsz, h, t, p), s_out.reshape(bsz, h, n, p)

@@ -1,16 +1,9 @@
-"""Pure-jnp oracles for every Pallas kernel in this package.
+"""Pure-jnp oracles for the Pallas kernels in this package.
 
-The block-tridiagonal factor/solve oracles are the production reference
-implementations from ``repro.core.block_lu`` (re-exported so kernel tests
-have a single import point).  The sequence-mixing oracles (WKV6 / SSD)
-are written as *naive sequential scans* -- the most obviously-correct
-formulation -- which the chunked Pallas kernels must match.
+The oracles are the production reference implementations from
+``repro.core.block_lu``, re-exported so kernel tests have a single import
+point.
 """
-
-from __future__ import annotations
-
-import jax
-import jax.numpy as jnp
 
 from repro.core.block_lu import (  # noqa: F401  (re-exports)
     BTFactors,
@@ -22,147 +15,3 @@ from repro.core.block_lu import (  # noqa: F401  (re-exports)
     fused_factor_spike_ref,
     gj_inverse,
 )
-
-
-# ---------------------------------------------------------------------------
-# RWKV6 WKV recurrence (matrix-valued state, per-channel data-dependent decay)
-# ---------------------------------------------------------------------------
-
-
-def wkv6_ref(
-    r: jax.Array,  # (B, H, T, D) receptance
-    k: jax.Array,  # (B, H, T, D) key
-    v: jax.Array,  # (B, H, T, D) value
-    logw: jax.Array,  # (B, H, T, D) log decay  (<= 0)
-    u: jax.Array,  # (H, D) current-token bonus
-    state: jax.Array,  # (B, H, D, D) initial state  [k-dim x v-dim]
-):
-    """Sequential WKV6:  S_t = diag(w_t) S_{t-1} + k_t v_t^T,
-    o_t = r_t^T (S_{t-1} + diag(u * k_t)?? ...) -- precisely:
-        o_t = r_t @ (S_{t-1} + (u * k_t) v_t^T)   per head.
-    Returns (o, state_out), o: (B, H, T, D)."""
-
-    def per_head(r, k, v, logw, u, s0):
-        def step(s, inp):
-            rt, kt, vt, lwt = inp
-            o = rt @ s + (rt * u * kt).sum() * vt
-            s = jnp.exp(lwt)[:, None] * s + kt[:, None] * vt[None, :]
-            return s, o
-
-        s_out, o = jax.lax.scan(step, s0, (r, k, v, logw))
-        return o, s_out
-
-    f = jax.vmap(jax.vmap(per_head))  # over B, H
-    u_b = jnp.broadcast_to(u, (r.shape[0],) + u.shape)
-    return f(r, k, v, logw, u_b, state)
-
-
-# ---------------------------------------------------------------------------
-# Mamba-2 SSD recurrence (scalar per-head decay, outer-product state)
-# ---------------------------------------------------------------------------
-
-
-def ssd_ref(
-    x: jax.Array,  # (B, H, T, P) inputs (already dt-scaled)
-    b: jax.Array,  # (B, H, T, N) input projection (dt-scaled B_t)
-    c: jax.Array,  # (B, H, T, N) output projection
-    loga: jax.Array,  # (B, H, T)   log decay (<= 0), already dt * A
-    state: jax.Array,  # (B, H, N, P) initial state
-):
-    """Sequential SSD:  h_t = exp(a_t) h_{t-1} + b_t x_t^T,  y_t = c_t @ h_t.
-    Returns (y, state_out), y: (B, H, T, P)."""
-
-    def per_head(x, b, c, loga, s0):
-        def step(s, inp):
-            xt, bt, ct, lat = inp
-            s = jnp.exp(lat) * s + bt[:, None] * xt[None, :]
-            y = ct @ s
-            return s, y
-
-        s_out, y = jax.lax.scan(step, s0, (x, b, c, loga))
-        return y, s_out
-
-    f = jax.vmap(jax.vmap(per_head))
-    return f(x, b, c, loga, state)
-
-
-# ---------------------------------------------------------------------------
-# Chunked (parallel-form) references: the SaP-scan formulation
-# ---------------------------------------------------------------------------
-
-
-def wkv6_chunked_ref(r, k, v, logw, u, state, chunk: int):
-    """Chunked WKV6 in plain jnp (the algorithm the kernel implements).
-
-    This is the paper's split-and-parallelize pattern applied to the
-    block-*bidiagonal* system defined by the recurrence: chunk-local solves
-    (intra-chunk term), plus spike/carry propagation (inter-chunk term).
-    All exponentials have non-positive arguments -> no overflow.
-    """
-    bsz, h, t, d = r.shape
-    nc = t // chunk
-
-    def per_head(r, k, v, logw, u, s0):
-        rc = r.reshape(nc, chunk, d)
-        kc = k.reshape(nc, chunk, d)
-        vc = v.reshape(nc, chunk, d)
-        lc = logw.reshape(nc, chunk, d)
-
-        def chunk_step(s, inp):
-            rj, kj, vj, lj = inp
-            lcum = jnp.cumsum(lj, axis=0)  # inclusive (C, D)
-            lprev = jnp.concatenate([jnp.zeros((1, d), lj.dtype), lcum[:-1]], 0)
-            # inter-chunk: o_t += (r_t * exp(Lprev_t)) @ S_in
-            o_inter = (rj * jnp.exp(lprev)) @ s
-            # intra-chunk: G[t, s<t] = sum_d r[t] k[s] exp(Lprev[t] - Lcum[s])
-            diff = lprev[:, None, :] - lcum[None, :, :]  # (C, C, D)
-            mask = (jnp.arange(chunk)[:, None] > jnp.arange(chunk)[None, :])
-            g = jnp.einsum("td,sd,tsd->ts", rj, kj, jnp.exp(diff)) * mask
-            diag = (rj * u[None, :] * kj).sum(-1)  # current-token bonus
-            o_intra = g @ vj + diag[:, None] * vj
-            # carry: S_out = diag(exp(Lcum_last)) S + (k*exp(Llast-Lcum))^T v
-            llast = lcum[-1]
-            s_new = jnp.exp(llast)[:, None] * s + (
-                (kj * jnp.exp(llast[None, :] - lcum)).T @ vj
-            )
-            return s_new, o_inter + o_intra
-
-        s_out, oc = jax.lax.scan(chunk_step, s0, (rc, kc, vc, lc))
-        return oc.reshape(t, d), s_out
-
-    f = jax.vmap(jax.vmap(per_head))
-    u_b = jnp.broadcast_to(u, (bsz,) + u.shape)
-    return f(r, k, v, logw, u_b, state)
-
-
-def ssd_chunked_ref(x, b, c, loga, state, chunk: int):
-    """Chunked SSD in plain jnp (the algorithm the kernel implements)."""
-    bsz, h, t, p = x.shape
-    n = b.shape[-1]
-    nc = t // chunk
-
-    def per_head(x, b, c, loga, s0):
-        xc = x.reshape(nc, chunk, p)
-        bc = b.reshape(nc, chunk, n)
-        cc = c.reshape(nc, chunk, n)
-        lc = loga.reshape(nc, chunk)
-
-        def chunk_step(s, inp):
-            xj, bj, cj, lj = inp
-            lcum = jnp.cumsum(lj)  # inclusive (C,)
-            # inter: y_t += exp(Lcum_t) c_t @ S_in
-            y_inter = jnp.exp(lcum)[:, None] * (cj @ s)
-            # intra: G[t,s<=t] = (c_t . b_s) exp(Lcum_t - Lcum_s)
-            diff = lcum[:, None] - lcum[None, :]
-            mask = jnp.arange(chunk)[:, None] >= jnp.arange(chunk)[None, :]
-            g = (cj @ bj.T) * jnp.exp(jnp.where(mask, diff, -jnp.inf))
-            y_intra = g @ xj
-            llast = lcum[-1]
-            s_new = jnp.exp(llast) * s + (bj * jnp.exp(llast - lcum)[:, None]).T @ xj
-            return s_new, y_inter + y_intra
-
-        s_out, yc = jax.lax.scan(chunk_step, s0, (xc, bc, cc, lc))
-        return yc.reshape(t, p), s_out
-
-    f = jax.vmap(jax.vmap(per_head))
-    return f(x, b, c, loga, state)

@@ -7,24 +7,24 @@ os.environ["XLA_FLAGS"] = (
     + os.environ.get("XLA_FLAGS", "")
 )
 
-"""Multi-pod dry-run driver.
+"""Multi-pod dry run of the distributed solver.
 
-Lowers + compiles every (arch x input-shape x mesh) cell on placeholder
-host devices and extracts memory analysis, cost analysis and the
-collective schedule for the roofline report.  THE XLA_FLAGS LINE ABOVE
-MUST STAY FIRST: jax locks the device count at first initialization.
+Lowers + compiles one (solver shape x mesh) cell on placeholder host
+devices and extracts memory analysis, cost analysis and the collective
+schedule for the roofline report.  THE XLA_FLAGS LINE ABOVE MUST STAY
+FIRST: jax locks the device count at first initialization.
 
 Usage:
-  python -m repro.launch.dryrun --arch phi3-mini-3.8b --shape train_4k
-  python -m repro.launch.dryrun --arch sap-solver --shape dense_200k --multi-pod
+  python -m repro.launch.dryrun --shape dense_200k --multi-pod
   python -m repro.launch.dryrun --list
-Options: --multi-pod, --out out.json, --zero1, --remat {none,full,dots},
-         --save-hlo hlo.txt, --variant {C,D,E} (solver; E = exact reduced
-         chain via distributed cyclic reduction).
+Options: --multi-pod, --out out.json, --save-hlo hlo.txt,
+         --variant {C,D,E} (E = exact reduced chain via distributed cyclic
+         reduction), --p-per-device, --precond-dtype {float32,bfloat16}.
+``--arch sap-solver`` is accepted (the only arch) so older command lines
+still work.
 """
 
 import argparse
-import dataclasses
 import json
 import sys
 import time
@@ -33,43 +33,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import optim
-from repro.configs import ARCHS, get_config
 from repro.configs.sap_solver import SOLVER_SHAPES
 from repro.launch.mesh import make_production_mesh
-from repro.launch.roofline import analyze, model_flops
-from repro.models import SHAPES, get_family, supports_shape
-
-OPT_CFG = optim.AdamWConfig()
-
-
-def _fsdp_pspecs(pspecs, param_shapes, mesh):
-    """FSDP/ZeRO-3: extend every 'model'-sharded weight dimension to
-    ('model','data') where divisible -- pjit all-gathers weights at use and
-    reduce-scatters their gradients."""
-    data = mesh.shape.get("data", 1)
-    model = mesh.shape.get("model", 1)
-
-    def one(spec, p):
-        if data <= 1 or p.ndim < 2:
-            return spec
-        entries = list(spec) + [None] * (p.ndim - len(spec))
-        for i, e in enumerate(entries):
-            if e == "model" and p.shape[i] % (model * data) == 0:
-                entries[i] = ("model", "data")
-                return P(*entries)
-        return spec
-
-    return jax.tree.map(one, pspecs, param_shapes,
-                        is_leaf=lambda x: isinstance(x, P))
-
-
-def _shardings(mesh, pspec_tree):
-    return jax.tree.map(
-        lambda s: NamedSharding(mesh, s),
-        pspec_tree,
-        is_leaf=lambda x: isinstance(x, P),
-    )
+from repro.launch.roofline import analyze
 
 
 def _mem_dict(compiled):
@@ -105,159 +71,6 @@ def _cost_dict(compiled):
     if isinstance(cost, (list, tuple)):
         cost = cost[0]
     return {k: float(v) for k, v in cost.items() if isinstance(v, (int, float))}
-
-
-# ---------------------------------------------------------------------------
-# LM cells
-# ---------------------------------------------------------------------------
-
-
-def lower_lm_cell(arch: str, shape_name: str, multi_pod: bool, args):
-    cfg = get_config(arch)
-    if args.remat:
-        cfg = dataclasses.replace(cfg, remat=args.remat)
-    if args.ssm_chunk:
-        cfg = dataclasses.replace(cfg, ssm_chunk=args.ssm_chunk)
-    if args.attn_block_k:
-        cfg = dataclasses.replace(cfg, attn_block_k=args.attn_block_k)
-    if args.scan_dtype:
-        cfg = dataclasses.replace(cfg, scan_dtype=args.scan_dtype)
-    shape = SHAPES[shape_name]
-    if not supports_shape(cfg, shape):
-        return {"arch": arch, "shape": shape_name, "skipped": "unsupported (full attention @ 500k)"}
-    fam = get_family(cfg)
-    mesh = make_production_mesh(multi_pod=multi_pod)
-    chips = int(mesh.devices.size)
-
-    param_shapes = jax.eval_shape(lambda: fam.init(cfg, jax.random.PRNGKey(0)))
-    if args.master_weights:
-        # bf16 distributed params; f32 master lives (sharded) in opt state
-        param_shapes = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, jnp.bfloat16), param_shapes
-        )
-    pspecs = fam.param_pspecs(cfg, mesh)
-    if args.fsdp:
-        pspecs = _fsdp_pspecs(pspecs, param_shapes, mesh)
-    param_sh = _shardings(mesh, pspecs)
-    in_specs = fam.input_specs(cfg, shape)
-    bspecs = fam.batch_pspecs(cfg, shape, mesh)
-    batch_sh = _shardings(mesh, bspecs)
-
-    with mesh:
-        if shape.kind == "train":
-            opt_cfg = dataclasses.replace(
-                OPT_CFG, master_weights=args.master_weights
-            )
-            opt_shapes = jax.eval_shape(
-                lambda p: optim.init(p, master_weights=args.master_weights),
-                param_shapes,
-            )
-            opt_pspecs = optim.opt_state_pspecs(
-                pspecs, param_shapes, mesh, zero1=args.zero1,
-                master_weights=args.master_weights,
-            )
-            opt_sh = _shardings(mesh, opt_pspecs)
-            nmicro = args.microbatches
-
-            def train_step(params, opt_state, batch):
-                def loss_fn(p, mb):
-                    l, _ = fam.loss(cfg, p, mb)
-                    return l
-
-                if nmicro == 1:
-                    l, grads = jax.value_and_grad(loss_fn)(params, batch)
-                else:
-                    def micro(carry, mb):
-                        acc, lacc = carry
-                        l, g = jax.value_and_grad(loss_fn)(params, mb)
-                        return (jax.tree.map(jnp.add, acc, g), lacc + l), None
-
-                    zeros = jax.tree.map(
-                        lambda p: jnp.zeros(p.shape, jnp.float32), params
-                    )
-                    mbs = jax.tree.map(
-                        lambda x: x.reshape(
-                            nmicro, x.shape[0] // nmicro, *x.shape[1:]
-                        ),
-                        batch,
-                    )
-                    (grads, lsum), _ = jax.lax.scan(micro, (zeros, 0.0), mbs)
-                    grads = jax.tree.map(lambda g: g / nmicro, grads)
-                    l = lsum / nmicro
-                params, opt_state, _ = optim.apply_updates(
-                    opt_cfg, params, grads, opt_state
-                )
-                return params, opt_state, l
-
-            jitted = jax.jit(
-                train_step,
-                in_shardings=(param_sh, opt_sh, batch_sh),
-                out_shardings=(param_sh, opt_sh, None),
-                donate_argnums=(0, 1),
-            )
-            lowered = jitted.lower(param_shapes, opt_shapes, in_specs)
-        elif shape.kind == "prefill":
-
-            def prefill_step(params, batch):
-                if cfg.family == "encdec":
-                    logits, _ = fam.forward(cfg, params, batch)
-                elif cfg.family in ("rwkv", "hybrid"):
-                    logits, _ = fam.forward(cfg, params, batch["tokens"])
-                else:
-                    logits, _ = fam.forward(
-                        cfg, params, batch["tokens"], batch.get("patches")
-                    )
-                return logits
-
-            jitted = jax.jit(
-                prefill_step, in_shardings=(param_sh, batch_sh), out_shardings=None
-            )
-            lowered = jitted.lower(param_shapes, in_specs)
-        else:  # decode
-
-            def serve_step(params, cache, tokens):
-                return fam.decode_step(cfg, params, cache, tokens)
-
-            jitted = jax.jit(
-                serve_step,
-                in_shardings=(param_sh, batch_sh["cache"], batch_sh["tokens"]),
-                out_shardings=(None, batch_sh["cache"]),
-                donate_argnums=(1,),
-            )
-            lowered = jitted.lower(param_shapes, in_specs["cache"], in_specs["tokens"])
-
-    t0 = time.time()
-    compiled = lowered.compile()
-    compile_s = time.time() - t0
-
-    hlo = compiled.as_text()
-    cost = _cost_dict(compiled)
-    roof = analyze(cost, hlo, chips, model_flops(cfg, shape))
-    row = {
-        "arch": arch,
-        "shape": shape_name,
-        "kind": shape.kind,
-        "mesh": "2x16x16" if multi_pod else "16x16",
-        "chips": chips,
-        "compile_s": round(compile_s, 1),
-        "memory": _mem_dict(compiled),
-        "cost": cost,
-        "roofline": roof.to_dict(),
-        "params": int(sum(
-            int(jnp.prod(jnp.asarray(p.shape)))
-            for p in jax.tree.leaves(param_shapes)
-        )),
-        "zero1": args.zero1,
-        "remat": cfg.remat,
-        "microbatches": args.microbatches,
-        "master_weights": args.master_weights,
-        "fsdp": args.fsdp,
-        "ssm_chunk": cfg.ssm_chunk,
-    }
-    if args.save_hlo:
-        with open(args.save_hlo, "w") as f:
-            f.write(hlo)
-    return row
 
 
 # ---------------------------------------------------------------------------
@@ -338,52 +151,37 @@ def lower_solver_cell(shape_name: str, multi_pod: bool, args):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default=None)
-    ap.add_argument("--shape", default=None)
+    ap.add_argument("--arch", default="sap-solver", choices=["sap-solver"])
+    ap.add_argument("--shape", default=None, choices=list(SOLVER_SHAPES))
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--out", default=None)
-    ap.add_argument("--zero1", action="store_true")
-    ap.add_argument("--remat", default=None, choices=[None, "none", "full", "dots"])
     ap.add_argument("--variant", default="C", choices=["C", "D", "E"])
     ap.add_argument("--p-per-device", type=int, default=1)
-    ap.add_argument("--microbatches", type=int, default=1)
-    ap.add_argument("--ssm-chunk", type=int, default=None)
-    ap.add_argument("--attn-block-k", type=int, default=None)
-    ap.add_argument("--scan-dtype", default=None)
-    ap.add_argument("--master-weights", action="store_true")
-    ap.add_argument("--fsdp", action="store_true")
     ap.add_argument("--precond-dtype", default="float32")
     ap.add_argument("--save-hlo", default=None)
     ap.add_argument("--list", action="store_true")
     args = ap.parse_args()
 
     if args.list:
-        for a in ARCHS:
-            cfg = get_config(a)
-            for s in SHAPES.values():
-                mark = "" if supports_shape(cfg, s) else " (skip)"
-                print(f"{a} x {s.name}{mark}")
         for s in SOLVER_SHAPES:
             print(f"sap-solver x {s}")
         return
+    if args.shape is None:
+        ap.error("--shape is required (see --list)")
 
-    if args.arch == "sap-solver":
-        row = lower_solver_cell(args.shape, args.multi_pod, args)
-    else:
-        row = lower_lm_cell(args.arch, args.shape, args.multi_pod, args)
+    row = lower_solver_cell(args.shape, args.multi_pod, args)
 
     js = json.dumps(row, indent=2, default=str)
     if args.out:
         with open(args.out, "w") as f:
             f.write(js)
     print(js)
-    if "memory" in row:
-        mem = row["memory"].get("total_per_device", 0)
-        print(
-            f"\n== {row['arch']} x {row['shape']} on {row['mesh']}: "
-            f"{mem/2**30:.2f} GiB/device, compile {row['compile_s']}s ==",
-            file=sys.stderr,
-        )
+    mem = row["memory"].get("total_per_device", 0)
+    print(
+        f"\n== {row['arch']} x {row['shape']} on {row['mesh']}: "
+        f"{mem/2**30:.2f} GiB/device, compile {row['compile_s']}s ==",
+        file=sys.stderr,
+    )
 
 
 if __name__ == "__main__":

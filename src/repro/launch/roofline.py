@@ -1,6 +1,6 @@
 """Roofline analysis from compiled XLA artifacts (no hardware needed).
 
-Three terms per (arch x shape x mesh) cell, all in seconds:
+Three terms per (shape x mesh) cell, all in seconds:
 
   compute    = HLO_FLOPs_per_device   / peak_FLOPs_per_chip
   memory     = HLO_bytes_per_device   / HBM_bandwidth_per_chip
@@ -334,36 +334,3 @@ def analyze(
         model_flops=model_flops_global,
         useful_ratio=useful,
     )
-
-
-# ---------------------------------------------------------------------------
-# MODEL_FLOPS (analytic "useful flops") per shape kind
-# ---------------------------------------------------------------------------
-
-
-def active_params(cfg) -> float:
-    """Parameters touched per token (MoE: routed top-k + shared experts
-    only; hybrid: the shared attention block is touched once per
-    application, i.e. n_layers/attn_every times)."""
-    total = cfg.params_count()
-    if cfg.n_experts:
-        mlp_one = cfg.d_model * cfg.d_ff * (3 if cfg.gated_mlp else 2)
-        n_blocks = cfg.n_layers
-        routed_all = cfg.n_experts * mlp_one * n_blocks
-        routed_active = cfg.top_k * mlp_one * n_blocks
-        return total - routed_all + routed_active
-    if cfg.family == "hybrid" and cfg.attn_every:
-        d, f, hd = cfg.d_model, cfg.d_ff, cfg.head_dim
-        attn = d * hd * (cfg.n_heads + 2 * cfg.n_kv_heads) + hd * cfg.n_heads * d
-        shared = attn + 3 * d * f
-        n_apps = cfg.n_layers // cfg.attn_every
-        return total + (n_apps - 1) * shared
-    return total
-
-
-def model_flops(cfg, shape) -> float:
-    """6 N D for training, 2 N D for inference forward passes."""
-    n_act = active_params(cfg)
-    tokens = shape.global_batch * (shape.seq_len if shape.kind != "decode" else 1)
-    mult = 6.0 if shape.kind == "train" else 2.0
-    return mult * n_act * tokens

@@ -1,4 +1,3 @@
-from .engine import Request, ServeEngine  # noqa: F401
 from .metrics import (  # noqa: F401
     Counter,
     Gauge,

@@ -1,8 +1,7 @@
 """Batched linear-solver serving engine: bucketed fleets, cached factors.
 
-The solver counterpart of :class:`repro.serve.engine.ServeEngine`: clients
-``submit()`` independent banded systems (one matrix + one RHS each) and
-the engine turns the pending queue into *batched* device work:
+Clients ``submit()`` independent banded systems (one matrix + one RHS
+each) and the engine turns the pending queue into *batched* device work:
 
 1. **Bucketing** -- each request's ``(N, K)`` rounds up to a compiled
    shape bucket (:func:`repro.core.batched.bucket_shape`); systems are

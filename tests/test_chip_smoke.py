@@ -156,6 +156,13 @@ def test_unknown_kernel_impl_raises(monkeypatch, value):
         ops.default_impl()
 
 
+@pytest.mark.parametrize("value", ops.IMPLS)
+def test_default_impl_honours_valid_env(monkeypatch, value):
+    """A valid REPRO_KERNEL_IMPL wins over the backend default."""
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", value)
+    assert ops.default_impl() == value
+
+
 def test_default_impl_on_cpu(monkeypatch):
     monkeypatch.delenv("REPRO_KERNEL_IMPL", raising=False)
     assert ops.default_impl() == "jnp"

@@ -142,18 +142,34 @@ def test_variant_e_same_solution_either_reduced_solver(reduced_solver):
     assert err < 1e-2
 
 
-def test_solver_config_maps_to_sap_options():
-    """The config-registry knobs reach the lifecycle API: the exact()
-    workload preset factors as variant E with the configured chain solver."""
-    from repro.configs.sap_solver import exact
+@pytest.mark.parametrize(
+    "preset,d,variant,reduced",
+    [
+        ("full", 1.0, "C", "none"),
+        ("reduced", 1.0, "C", "none"),
+        ("exact", 0.5, "E", "bcr"),  # 15 interfaces -> auto = bcr
+        ("service", 0.5, "E", "bcr"),  # "auto" resolves to E below d = 1
+        ("fleet", 1.0, "C", "none"),
+    ],
+    ids=["full", "reduced", "exact", "service", "fleet"],
+)
+def test_solver_config_maps_to_sap_options(preset, d, variant, reduced):
+    """The config-registry knobs reach the lifecycle API: each workload
+    preset's options carry its fields, and a matrix of dominance ``d``
+    factors as the variant (and reduced-chain solver) the preset picks."""
+    from repro.configs import sap_solver
 
-    cfg = exact()
+    cfg = getattr(sap_solver, preset)()
     opts = cfg.to_sap_options(p=16)
-    assert (opts.variant, opts.reduced_solver) == ("E", "auto")
-    band = jnp.asarray(oscillatory_banded(512, 6, d=cfg.d, seed=3), jnp.float32)
+    assert opts.p == 16
+    assert (opts.variant, opts.reduced_solver) == (cfg.variant,
+                                                   cfg.reduced_solver)
+    assert (opts.tol, opts.maxiter, opts.precond_dtype) == (
+        cfg.tol, cfg.maxiter, cfg.precond_dtype)
+    band = jnp.asarray(oscillatory_banded(512, 6, d=d, seed=3), jnp.float32)
     fac = factor(plan_banded(band, opts))
-    assert fac.variant == "E"
-    assert fac.pc.reduced_solver == "bcr"  # 15 interfaces -> auto = bcr
+    assert fac.variant == variant
+    assert fac.pc.reduced_solver == reduced
 
 
 def test_reduced_solver_choice_in_info():

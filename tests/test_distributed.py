@@ -2,6 +2,7 @@
 (the in-process suite must keep seeing exactly 1 device)."""
 
 import json
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -126,48 +127,13 @@ def test_distributed_exact_variant_f64_agrees_with_single_device():
     assert "DIST_E_F64_OK" in proc.stdout
 
 
-DIST_TRAIN = r"""
-import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.configs import get_config
-from repro.models import get_family
-from repro import optim
-from repro.launch.mesh import make_test_mesh
-mesh = make_test_mesh((2, 4), ("data", "model"))
-cfg = get_config("stablelm-1.6b", reduced=True)
-fam = get_family(cfg)
-params = fam.init(cfg, jax.random.PRNGKey(0))
-pspecs = fam.param_pspecs(cfg, mesh)
-shard = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
-                     is_leaf=lambda x: isinstance(x, P))
-params_sh = jax.device_put(params, shard)
-batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, cfg.vocab)}
-
-def loss_fn(p, b):
-    l, _ = fam.loss(cfg, p, b)
-    return l
-
-with mesh:
-    l_sh = jax.jit(loss_fn)(params_sh, batch)
-l_local = loss_fn(params, batch)
-assert abs(float(l_sh) - float(l_local)) < 1e-3, (float(l_sh), float(l_local))
-print("DIST_TRAIN_OK")
-"""
-
-
-def test_sharded_loss_matches_single_device():
-    proc = _run(DIST_TRAIN)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    assert "DIST_TRAIN_OK" in proc.stdout
-
-
 @pytest.mark.parametrize("mesh_flag", ["", "--multi-pod"])
 def test_dryrun_cell_compiles_on_test_mesh(mesh_flag, tmp_path):
     """End-to-end dryrun driver on the scaled-down mesh (8 devices)."""
     out = tmp_path / "cell.json"
     cmd = [
         sys.executable, "-m", "repro.launch.dryrun",
-        "--arch", "stablelm-1.6b", "--shape", "decode_32k",
+        "--arch", "sap-solver", "--shape", "dense_200k",
         "--out", str(out),
     ] + ([mesh_flag] if mesh_flag else [])
     env = {
@@ -190,6 +156,7 @@ import sys
 sys.argv = ["dryrun", "--arch", "sap-solver", "--shape", "dense_200k"]
 from repro.launch import dryrun
 import json
+import os
 row = dryrun.lower_solver_cell("dense_200k", False, type("A", (), {
     "variant": "C", "p_per_device": 1, "save_hlo": None,
     "precond_dtype": "float32"})())
@@ -202,3 +169,74 @@ def test_solver_dryrun_has_neighbor_collectives():
     proc = _run(SOLVER_DRYRUN)
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "SOLVER_DRYRUN_OK" in proc.stdout
+
+
+SOLVER_DRYRUN_E = r"""
+from repro.launch import dryrun
+row = dryrun.lower_solver_cell("dense_200k", False, type("A", (), {
+    "variant": "E", "p_per_device": 1, "save_hlo": None,
+    "precond_dtype": "float32"})())
+assert row["kind"] == "solve-E" and row["chips"] == 8, row
+assert row["roofline"]["coll_bytes"] > 0  # the PCR sweep's ppermutes
+print("SOLVER_DRYRUN_E_OK")
+"""
+
+
+def test_solver_dryrun_variant_e_lowers_on_test_mesh():
+    """Variant E (distributed cyclic reduction) lowers and compiles on
+    the 8-device test mesh."""
+    proc = _run(SOLVER_DRYRUN_E)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "SOLVER_DRYRUN_E_OK" in proc.stdout
+
+
+def _dryrun_cli(*args):
+    env = dict(os.environ, PYTHONPATH=str(SRC), REPRO_DRYRUN_DEVICES="8",
+               JAX_PLATFORMS="cpu")
+    return subprocess.run(
+        [sys.executable, "-m", "repro.launch.dryrun", *args],
+        capture_output=True, text=True, timeout=300, env=env)
+
+
+def test_dryrun_list_prints_solver_shapes():
+    from repro.configs.sap_solver import SOLVER_SHAPES
+
+    proc = _dryrun_cli("--list")
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.splitlines() == [f"sap-solver x {s}"
+                                        for s in SOLVER_SHAPES]
+
+
+@pytest.mark.parametrize("args", [
+    ("--arch", "stablelm-1.6b", "--shape", "dense_200k"),
+    ("--zero1", "--shape", "dense_200k"),
+    ("--variant", "E"),
+], ids=["lm_arch", "lm_flag", "no_shape"])
+def test_dryrun_cli_accepts_solver_cells_only(args):
+    """The dry run lowers solver cells only: an LM arch, an LM flag or a
+    missing --shape is a usage error (exit 2) before anything compiles."""
+    proc = _dryrun_cli(*args)
+    assert proc.returncode == 2, proc.stderr[-3000:]
+    assert "usage:" in proc.stderr
+
+
+MESH = r"""
+import json
+import os
+from repro.launch.mesh import make_production_mesh
+out = {}
+for multi_pod in (False, True):
+    mesh = make_production_mesh(multi_pod=multi_pod)
+    out[str(multi_pod)] = [list(mesh.axis_names), list(mesh.devices.shape)]
+print(json.dumps(out))
+"""
+
+
+def test_production_mesh_scales_down_to_available_devices():
+    """Below 256 / 512 devices the production mesh keeps its axis names
+    and folds the devices into a (2, n/2) or (2, 2, n/4) stand-in."""
+    proc = _run(MESH)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["False"] == [["data", "model"], [2, 4]]
+    assert out["True"] == [["pod", "data", "model"], [2, 2, 2]]

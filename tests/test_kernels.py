@@ -1,7 +1,6 @@
 """Pallas kernel validation: interpret-mode vs pure-jnp oracle, shape/dtype
 sweeps (per-kernel allclose against ref.py)."""
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -106,154 +105,5 @@ def test_btf_pivot_boost_in_kernel():
     assert bool(jnp.all(jnp.isfinite(fac.sinv)))
 
 
-# ---------------------------------------------------------------------------
-# WKV6 chunked kernel
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("b,h,t,dd,chunk", [(1, 1, 32, 8, 8), (2, 3, 64, 16, 16),
-                                            (1, 2, 96, 8, 32)])
-def test_wkv6_kernel_vs_sequential(b, h, t, dd, chunk, dtype):
-    if dtype == jnp.bfloat16 and t > 64:
-        pytest.skip("bf16 cumsum drift beyond tolerance at long T")
-    rng = np.random.default_rng(2)
-    r = _rand(rng, (b, h, t, dd))
-    k = _rand(rng, (b, h, t, dd))
-    v = _rand(rng, (b, h, t, dd))
-    logw = -jnp.exp(_rand(rng, (b, h, t, dd)) * 0.5)
-    u = _rand(rng, (h, dd))
-    s0 = _rand(rng, (b, h, dd, dd)) * 0.1
-    o_ref, s_ref = ref.wkv6_ref(r, k, v, logw, u, s0)
-    if dtype == jnp.bfloat16:
-        r, k, v = (x.astype(dtype) for x in (r, k, v))
-    o_pl, s_pl = ops.wkv6(r, k, v, logw, u, s0, chunk=chunk, impl="interpret")
-    tol = 2e-4 if dtype == jnp.float32 else 1e-1
-    np.testing.assert_allclose(np.asarray(o_ref, np.float32),
-                               np.asarray(o_pl, np.float32), rtol=tol,
-                               atol=tol)
-    np.testing.assert_allclose(np.asarray(s_ref, np.float32),
-                               np.asarray(s_pl, np.float32), rtol=tol,
-                               atol=tol)
-
-
-def test_wkv6_strong_decay_no_overflow():
-    """Strong decay (log w << 0) must stay finite: the chunked form only
-    exponentiates non-positive numbers (see kernel docstring)."""
-    rng = np.random.default_rng(3)
-    b, h, t, dd = 1, 1, 64, 8
-    r = _rand(rng, (b, h, t, dd))
-    k = _rand(rng, (b, h, t, dd))
-    v = _rand(rng, (b, h, t, dd))
-    logw = jnp.full((b, h, t, dd), -30.0)
-    u = _rand(rng, (h, dd))
-    s0 = jnp.zeros((b, h, dd, dd))
-    o, s = ops.wkv6(r, k, v, logw, u, s0, chunk=16, impl="interpret")
-    assert bool(jnp.all(jnp.isfinite(o))) and bool(jnp.all(jnp.isfinite(s)))
-
-
-# ---------------------------------------------------------------------------
-# SSD chunked kernel
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("b,h,t,n,pd,chunk", [(1, 1, 32, 4, 8, 8),
-                                              (2, 2, 64, 8, 16, 16),
-                                              (1, 3, 96, 16, 8, 32)])
-def test_ssd_kernel_vs_sequential(b, h, t, n, pd, chunk):
-    rng = np.random.default_rng(4)
-    x = _rand(rng, (b, h, t, pd))
-    bm = _rand(rng, (b, h, t, n))
-    cm = _rand(rng, (b, h, t, n))
-    la = -jnp.exp(_rand(rng, (b, h, t)) * 0.5)
-    s0 = _rand(rng, (b, h, n, pd)) * 0.1
-    y_ref, s_ref = ref.ssd_ref(x, bm, cm, la, s0)
-    y_pl, s_pl = ops.ssd(x, bm, cm, la, s0, chunk=chunk, impl="interpret")
-    np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y_pl), rtol=2e-4,
-                               atol=2e-4)
-    np.testing.assert_allclose(np.asarray(s_ref), np.asarray(s_pl), rtol=2e-4,
-                               atol=2e-4)
-
-
-def test_ssd_state_carry_across_calls():
-    """Chunked call over [0:T/2] then [T/2:T] == one call over [0:T]."""
-    rng = np.random.default_rng(5)
-    b, h, t, n, pd = 1, 2, 64, 8, 8
-    x = _rand(rng, (b, h, t, pd))
-    bm = _rand(rng, (b, h, t, n))
-    cm = _rand(rng, (b, h, t, n))
-    la = -jnp.exp(_rand(rng, (b, h, t)) * 0.5)
-    s0 = jnp.zeros((b, h, n, pd))
-    y_full, s_full = ops.ssd(x, bm, cm, la, s0, chunk=16, impl="jnp")
-    y1, s1 = ops.ssd(x[:, :, :32], bm[:, :, :32], cm[:, :, :32], la[:, :, :32],
-                     s0, chunk=16, impl="jnp")
-    y2, s2 = ops.ssd(x[:, :, 32:], bm[:, :, 32:], cm[:, :, 32:], la[:, :, 32:],
-                     s1, chunk=16, impl="jnp")
-    np.testing.assert_allclose(np.asarray(y_full),
-                               np.concatenate([y1, y2], axis=2), rtol=1e-4,
-                               atol=1e-5)
-    np.testing.assert_allclose(np.asarray(s_full), np.asarray(s2), rtol=1e-4,
-                               atol=1e-5)
-
-
 def test_default_impl_is_jnp_on_cpu():
     assert ops.default_impl() == "jnp"
-
-
-# ---------------------------------------------------------------------------
-# Flash attention kernel (beyond-paper; see EXPERIMENTS.md section Perf)
-# ---------------------------------------------------------------------------
-
-
-def _dense_attn(q, k, v, causal, window):
-    b, hq, tq, d = q.shape
-    hk = k.shape[1]
-    g = hq // hk
-    qg = q.reshape(b, hk, g, tq, d)
-    s = jnp.einsum("bhgtd,bhsd->bhgts", qg, k) / np.sqrt(d)
-    qp = jnp.arange(tq)[:, None]
-    kp = jnp.arange(k.shape[2])[None, :]
-    m = jnp.ones((tq, k.shape[2]), bool)
-    if causal:
-        m &= qp >= kp
-    if window:
-        m &= qp - kp < window
-    s = jnp.where(m, s, -1e30)
-    p = jax.nn.softmax(s, -1)
-    return jnp.einsum("bhgts,bhsd->bhgtd", p, v).reshape(b, hq, tq, d)
-
-
-@pytest.mark.parametrize(
-    "b,hq,hk,t,d,causal,window",
-    [
-        (1, 2, 2, 128, 16, True, None),
-        (2, 4, 2, 128, 32, True, None),  # GQA
-        (1, 4, 1, 256, 16, True, 64),  # GQA + sliding window
-        (1, 2, 2, 128, 16, False, None),  # bidirectional (encoder)
-    ],
-)
-def test_flash_attention_kernel_vs_dense(b, hq, hk, t, d, causal, window):
-    from repro.kernels.flash_attn import flash_attention_pallas
-
-    rng = np.random.default_rng(7)
-    q = _rand(rng, (b, hq, t, d))
-    k = _rand(rng, (b, hk, t, d))
-    v = _rand(rng, (b, hk, t, d))
-    truth = _dense_attn(q, k, v, causal, window)
-    out = flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                 block_q=64, block_k=64, interpret=True)
-    np.testing.assert_allclose(np.asarray(truth), np.asarray(out), rtol=2e-4,
-                               atol=2e-5)
-
-
-def test_flash_ref_no_nan_under_window_blocks():
-    """Regression: -inf masking produced NaN for fully-masked (row, block)
-    pairs; the finite NEG_INF formulation must not."""
-    from repro.models.layers import flash_attention
-
-    rng = np.random.default_rng(8)
-    q = _rand(rng, (1, 2, 256, 16))
-    k = _rand(rng, (1, 2, 256, 16))
-    v = _rand(rng, (1, 2, 256, 16))
-    o = flash_attention(q, k, v, causal=True, window=64, block_k=64)
-    assert bool(jnp.all(jnp.isfinite(o)))

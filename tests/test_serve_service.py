@@ -17,7 +17,7 @@ from repro.serve import (
     SolveCancelled,
     band_dominance,
 )
-from repro.serve.metrics import Counter, Histogram
+from repro.serve.metrics import Counter, Gauge, Histogram
 
 
 def _mat(n, k, seed, d=1.1):
@@ -70,6 +70,44 @@ def test_metrics_counter_and_histogram():
     full = reg.snapshot()
     assert full["counters"]["reqs"] == 3
     assert full["histograms"]["lat"]["count"] == 4
+
+
+def test_gauge_set_inc_dec():
+    g = Gauge("depth")
+    assert g.value == 0.0
+    g.set(5)
+    g.inc()
+    g.inc(2.5)
+    g.dec()
+    g.dec(0.5)
+    assert g.value == 7.0
+    g.dec(10)  # a level may go negative, unlike a counter
+    assert g.value == -3.0
+    g.set(1)
+    assert g.value == 1.0 and isinstance(g.value, float)
+
+
+def test_gauge_set_max_is_a_watermark():
+    g = Gauge("peak_device_bytes")
+    for v in (3, 9, 4):
+        g.set_max(v)
+    assert g.value == 9.0
+    g.set(2)  # set() lowers it, set_max() never does
+    g.set_max(1)
+    assert g.value == 2.0
+
+
+def test_gauge_snapshot_in_registry():
+    reg = MetricsRegistry()
+    g = reg.gauge("pending_now")
+    assert reg.gauge("pending_now") is g
+    g.set(4)
+    assert g.snapshot() == 4.0
+    assert reg.snapshot()["gauges"] == {"pending_now": 4.0}
+    g.dec()
+    assert reg.snapshot()["gauges"]["pending_now"] == 3.0
+    with pytest.raises(ValueError):
+        reg.counter("pending_now")  # name already used by the gauge
 
 
 def test_metrics_thread_safety():

@@ -7,7 +7,12 @@ import numpy as np
 import pytest
 
 from repro.core import SaPOptions, batched
-from repro.core.banded import band_matvec, random_banded
+from repro.core.banded import (
+    band_matvec,
+    band_to_dense,
+    oscillatory_banded,
+    random_banded,
+)
 from repro.core.krylov import bicgstab2_applies
 from repro.serve import SolveRequest, SolverEngine, matrix_fingerprint
 
@@ -53,6 +58,29 @@ def test_engine_solves_heterogeneous_fleet():
         assert r.result.x.shape == x.shape  # un-padded to original N
         err = np.linalg.norm(r.result.x - x) / np.linalg.norm(x)
         assert err < 1e-3
+
+
+@pytest.mark.parametrize("variant,d", [("D", 1.1), ("E", 0.5)])
+def test_engine_solves_fleet_under_variant(variant, d):
+    """The batched engine under the decoupled (D) and exact reduced (E)
+    variants, the latter on non-dominant matrices with non-decaying
+    spikes, against dense f64 solves."""
+    eng = SolverEngine(
+        SaPOptions(p=4, variant=variant, tol=1e-6, maxiter=300), max_batch=8
+    )
+    truth = {}
+    for i in range(3):
+        band = np.float32(oscillatory_banded(192, 3, d=d, seed=20 + i))
+        dense = np.asarray(band_to_dense(jnp.asarray(band)), np.float64)
+        b = np.float32(dense @ np.random.default_rng(i).normal(size=192))
+        truth[eng.submit_system(band, b)] = np.linalg.solve(dense, b)
+    done = eng.run_until_drained()
+    assert len(done) == 3 and eng.stats["steps"] == 1
+    for r in done:
+        assert r.result.variant == variant
+        assert r.result.converged and not r.result.misconverged
+        x = truth[r.rid]
+        assert np.linalg.norm(r.result.x - x) / np.linalg.norm(x) < 1e-3
 
 
 def test_engine_factor_runs_once_for_repeated_fingerprints(monkeypatch):
