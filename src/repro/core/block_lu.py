@@ -250,7 +250,7 @@ def btf_ref(
         xs = (dp[1:], ep[1:], fp[:-1])
         _, (sinv_rest, l_rest) = jax.lax.scan(step, sinv0, xs)
         sinv = jnp.concatenate([sinv0[None], sinv_rest], axis=0)
-        l = jnp.concatenate([jnp.zeros_like(l_rest[:1]), l_rest], axis=0)
+        l = jnp.concatenate([jnp.zeros_like(sinv0)[None], l_rest], axis=0)
         return sinv, l
 
     sinv, l = jax.vmap(one_partition)(d, e, f)

@@ -1,11 +1,12 @@
 """Pallas kernel validation: interpret-mode vs pure-jnp oracle, shape/dtype
 sweeps (per-kernel allclose against ref.py)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import bts, ops, ref
 
 
 def _rand(rng, shape, dtype=jnp.float32):
@@ -39,10 +40,20 @@ def test_btf_matches_ref(p, m, k, dtype):
         np.asarray(fr.l, np.float32), np.asarray(fp.l, np.float32), **tol)
 
 
-@pytest.mark.parametrize("p,m,k,r", [(2, 3, 4, 1), (1, 6, 8, 8), (3, 2, 16, 4)])
+@pytest.mark.parametrize("p,m,k,r", [
+    (2, 3, 4, 1), (1, 6, 8, 8), (3, 2, 16, 4),
+    (16, 3, 16, 1),   # the fleet's blocks: all 16 chains in one grid step
+    (16, 2, 200, 4),  # the dense blocks: tiles of 8, a proper divisor
+    (11, 2, 200, 1),  # no divisor of 11 but 1 fits: one chain a step
+    (5, 1, 8, 2),     # M = 1: the first block row is the last
+    (4, 3, 16, 16),   # R = K: the spikes' solve
+])
 def test_bts_matches_ref(p, m, k, r):
     rng = np.random.default_rng(1)
-    d = _rand(rng, (p, m, k, k)) + 4 * jnp.eye(k)
+    # A shift that grows with K keeps wide blocks as well conditioned as
+    # narrow ones (a K x K normal block has eigenvalues out to sqrt(K)), so
+    # the f32 rounding of two summation orders stays within the tolerance.
+    d = _rand(rng, (p, m, k, k)) + max(4.0, k / 8) * jnp.eye(k)
     e = _rand(rng, (p, m, k, k)) * 0.3
     f = _rand(rng, (p, m, k, k)) * 0.3
     fac = ref.btf_ref(d, e, f)
@@ -51,6 +62,65 @@ def test_bts_matches_ref(p, m, k, r):
     xp = ops.block_tridiag_solve(fac, b, impl="interpret")
     np.testing.assert_allclose(np.asarray(xr), np.asarray(xp), rtol=1e-5,
                                atol=1e-6)
+
+
+def test_bts_bf16_storage_matches_ref():
+    """bf16 blocks and RHS: the kernel computes in f32 and stores bf16, so
+    the oracle is the f32 solve of the same stored values."""
+    rng = np.random.default_rng(2)
+    p, m, k, r = 4, 3, 16, 1
+    d = _rand(rng, (p, m, k, k)) + 4 * jnp.eye(k)
+    e = _rand(rng, (p, m, k, k)) * 0.3
+    f = _rand(rng, (p, m, k, k)) * 0.3
+    fac = ref.btf_ref(d, e, f)
+    fac16 = type(fac)(*(x.astype(jnp.bfloat16) for x in fac))
+    b16 = _rand(rng, (p, m, k, r), jnp.bfloat16)
+    xr = ref.bts_ref(type(fac)(*(x.astype(jnp.float32) for x in fac16)),
+                     b16.astype(jnp.float32))
+    xp = ops.block_tridiag_solve(fac16, b16, impl="interpret")
+    assert xp.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(xr), np.asarray(xp, np.float32),
+                               rtol=2e-2, atol=2e-2)
+
+
+def test_bts_under_vmap_matches_ref():
+    """The fleet's path: the engine vmaps the solve over systems, so Pallas
+    puts the system axis in front of the (P // pt, M) grid."""
+    rng = np.random.default_rng(3)
+    s, p, m, k, r = 3, 16, 3, 16, 1
+    d = _rand(rng, (s, p, m, k, k)) + 4 * jnp.eye(k)
+    e = _rand(rng, (s, p, m, k, k)) * 0.3
+    f = _rand(rng, (s, p, m, k, k)) * 0.3
+    b = _rand(rng, (s, p, m, k, r))
+    fac = jax.vmap(ref.btf_ref)(d, e, f)
+    xp = jax.vmap(
+        lambda fc, bi: ops.block_tridiag_solve(fc, bi, impl="interpret")
+    )(fac, b)
+    for i in range(s):
+        fac_i = ref.btf_ref(d[i], e[i], f[i])
+        np.testing.assert_allclose(
+            np.asarray(ref.bts_ref(fac_i, b[i])), np.asarray(xp[i]),
+            rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("p,k,r,dtype,tile", [
+    (16, 16, 1, jnp.float32, 16),    # the fleet
+    (16, 200, 1, jnp.float32, 8),    # the dense cell (R 1 under its vmap)
+    (16, 200, 4, jnp.float32, 8),
+    (16, 200, 200, jnp.float32, 4),  # the dense spikes
+    (16, 200, 4, jnp.bfloat16, 8),
+    (11, 200, 1, jnp.float32, 1),
+    (1, 400, 1, jnp.float32, 1),     # the variant-E chain
+])
+def test_bts_partition_tile(p, k, r, dtype, tile):
+    """The tile is a pure function of the shape: a divisor of P whose
+    double-buffered blocks fit the VMEM budget, the largest the rule
+    admits."""
+    t = bts.partition_tile(p, k, r, dtype)
+    assert t == tile and p % t == 0
+    blocks = 2 * (2 * bts._vmem_bytes(k, k, dtype)
+                  + 2 * bts._vmem_bytes(k, r, dtype))
+    assert t * blocks <= bts.VMEM_BUDGET
 
 
 @pytest.mark.parametrize("s,p,m,k,r", [(2, 3, 4, 4, 2), (4, 1, 3, 8, 1)])

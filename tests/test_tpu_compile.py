@@ -106,3 +106,18 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, shape):
     ]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_bts_compiles_for_v5e_under_vmap_at_fleet_shape(one_chip):
+    """The fleet's path: the engine vmaps the solve over its 64 systems, so
+    Pallas puts the system axis in front of the (P // pt, M) grid.  At
+    (16, 64, 16, 16), R 1, all 16 partition chains share a grid step; a
+    tile that overran scoped VMEM would fail here."""
+    s, p, m, k = 64, 16, 64, 16
+    fn = jax.vmap(lambda a, l, f, b: bts_pallas(a, l, f, b, interpret=False))
+    args = [
+        jax.ShapeDtypeStruct(x, jnp.float32, sharding=one_chip)
+        for x in [(s, p, m, k, k)] * 3 + [(s, p, m, k, 1)]
+    ]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
